@@ -81,6 +81,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir, "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import (  # noqa: E402
     EventTrace,
     JRBAEngine,
@@ -977,6 +978,7 @@ def main() -> None:
         "trace-event file (loadable in Perfetto / chrome://tracing)",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     # every artifact derives from the --out stem (CI names them the same way)
     stem = os.path.splitext(args.out)[0]

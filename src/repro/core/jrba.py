@@ -111,7 +111,7 @@ class FlowProgram:
 
     Alongside the dense ``usage`` tensor the program carries the sparse
     formulation: ``link_idx`` (the padded path->link index tensor), the
-    active link set, and the active-compressed usage/index tensors the
+    active link set, and the active-compressed usage tensor the
     sparse solver actually consumes. Everything except ``capacity``,
     ``volumes`` and ``flows`` depends only on topology + candidate paths, so
     the engine's program cache shares these tensors (and their device
@@ -127,7 +127,6 @@ class FlowProgram:
     link_idx: np.ndarray  # (Nf, K, Pmax) int32; padding slots hold L
     active_links: np.ndarray  # (La,) int32 — links on any candidate path
     usage_active: np.ndarray  # (Nf, K, La_pad) — usage gathered to active slots
-    ridx: np.ndarray  # (Nf, K, Pmax) int32 remapped to [0, La_pad]
     # lazily-populated device mirrors of the solve-invariant tensors above;
     # shared (same dict object) across cache-replayed copies of this program
     dev: dict = dataclasses.field(default_factory=dict, repr=False, compare=False)
@@ -210,8 +209,6 @@ def build_program(
     while la_pad < la:
         la_pad *= 2
     la_pad = min(la_pad, L)
-    remap = np.full(L + 1, la_pad, dtype=np.int32)
-    remap[active] = np.arange(la, dtype=np.int32)
     usage_active = np.zeros((Nf, k, la_pad), dtype=np.float32)
     usage_active[:, :, :la] = usage[:, :, active]
     return FlowProgram(
@@ -225,7 +222,6 @@ def build_program(
         link_idx=link_idx,
         active_links=active,
         usage_active=usage_active,
-        ridx=remap[link_idx],
     )
 
 
@@ -442,12 +438,14 @@ def solve_relaxation(prog: FlowProgram, *, n_iters: int = 400) -> tuple[np.ndarr
     return m, float(span)
 
 
-def _sparse_dispatch(backend: str, interpret: bool):
+def _sparse_solver(backend: str, interpret: bool):
+    """The batched sparse solver of ``backend``; both take the same operands
+    (the active-link usage leads) and return ``(w, span, steps)``."""
     if backend == "pallas":
         from ..kernels.jrba_congestion import sparse_congestion_solve
 
         return functools.partial(sparse_congestion_solve, interpret=interpret)
-    return None
+    return _solve_sparse_batched
 
 
 def solve_relaxation_sparse(
@@ -470,11 +468,8 @@ def solve_relaxation_sparse(
     batched solves share one compiled structure."""
     cap_a = jnp.asarray(prog.capacity_active())
     n_out = jnp.float32(len(prog.capacity) - prog.la_pad)
-    kernel = _sparse_dispatch(backend, interpret)
-    solver = kernel if kernel is not None else _solve_sparse_batched
-    lead = prog.device("ridx") if kernel is not None else prog.device("usage_active")
-    w, span, steps = solver(
-        lead[None],
+    w, span, steps = _sparse_solver(backend, interpret)(
+        prog.device("usage_active")[None],
         prog.device("valid")[None],
         prog.device("volumes")[None],
         cap_a[None],
@@ -501,11 +496,8 @@ def solve_relaxation_sparse_batch(
 ) -> list[tuple[np.ndarray, float, int]]:
     """Sparse twin of :func:`solve_relaxation_batch`; one vmapped (or
     Pallas-gridded) dispatch for N same-shape programs, one device sync for
-    all results. Programs must share the (Nf, K, La_pad) bucket — plus Pmax
-    for the Pallas backend, whose kernel shape includes the hop axis (the
-    jnp path never touches the index tensor, so mixed-Pmax groups batch)."""
-    kernel = _sparse_dispatch(backend, interpret)
-    shapes = {(p.valid.shape, p.la_pad) + ((p.ridx.shape[-1],) if kernel else ()) for p in progs}
+    all results. Programs must share the (Nf, K, La_pad) bucket."""
+    shapes = {(p.valid.shape, p.la_pad) for p in progs}
     if len(shapes) != 1:
         raise ValueError(f"programs span multiple sparse buckets: {sorted(shapes)}")
     # host-side stack + one upload per operand (see solve_relaxation_batch)
@@ -515,10 +507,8 @@ def solve_relaxation_sparse_batch(
     n_out = jnp.asarray(
         np.array([len(p.capacity) - p.la_pad for p in progs], dtype=np.float32)
     )
-    solver = kernel if kernel is not None else _solve_sparse_batched
-    lead = np.stack([p.ridx if kernel is not None else p.usage_active for p in progs])
-    w, spans, steps = solver(
-        jnp.asarray(lead),
+    w, spans, steps = _sparse_solver(backend, interpret)(
+        jnp.asarray(np.stack([p.usage_active for p in progs])),
         valid,
         volumes,
         cap_a,
@@ -966,7 +956,7 @@ class JRBAEngine:
         For the dense solver the key — ``(Nf bucket, k, L)`` — is exactly the
         compiled-shape signature, so one queued bucket is one vmapped call.
         Sparse/Pallas signatures additionally depend on the active-link
-        compression (``La_pad``, ``Pmax``), which only the built program
+        compression (``La_pad``), which only the built program
         knows; there the key is a *proxy* — programs sharing it usually share
         a compiled shape, and ``solve_many`` re-buckets exactly inside the
         dispatch, so a mixed bucket costs extra compiled calls, never a wrong
@@ -981,15 +971,10 @@ class JRBAEngine:
     def _shape_key(self, prog: FlowProgram) -> tuple:
         """Compiled-signature key of one program under the active solver.
         Sparse solves never see L, so instances from different topologies
-        share a signature whenever their active-compressed shapes agree;
-        only the Pallas kernel additionally specializes on the hop axis
-        (Pmax) of the index tensor."""
+        share a signature whenever their active-compressed shapes agree."""
         if self.solver == "dense":
             return prog.usage.shape
-        key = ("sp", *prog.valid.shape, prog.la_pad)
-        if self.solver.startswith("pallas"):
-            key += (prog.ridx.shape[-1],)
-        return key
+        return ("sp", *prog.valid.shape, prog.la_pad)
 
     def build(
         self,

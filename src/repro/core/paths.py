@@ -126,23 +126,16 @@ def path_link_index(
     *,
     k: int,
     rows: int,
-    pmax: int | None = None,
 ) -> np.ndarray:
     """Padded path->link index tensor ``(rows, k, pmax)``: entry ``[i, kk, p]``
-    is the link id of hop ``p`` of candidate path ``kk`` of flow ``i``. Unused
-    slots (short paths, missing candidates, shape-padding rows) hold the
-    sentinel ``L = len(net.links)`` — a dummy scatter bin the sparse JRBA
-    solver drops, so no separate mask tensor is needed. ``pmax`` defaults to
-    the longest candidate path rounded up to a power of two (>= 4), keeping
-    the jitted solver on O(log) distinct hop-count shapes."""
+    is the link id of hop ``p`` of candidate path ``kk`` of flow ``i``, and
+    ``pmax`` is the longest candidate path's hop count. Unused slots (short
+    paths, missing candidates, shape-padding rows) hold the sentinel
+    ``L = len(net.links)``, so the active-link set the sparse JRBA solver
+    compresses onto is ``unique(idx[idx < L])`` with no separate mask
+    tensor."""
     L = len(net.links)
-    longest = max((len(p) - 1 for ps in all_paths for p in ps[:k]), default=1)
-    if pmax is None:
-        pmax = 4
-        while pmax < longest:
-            pmax *= 2
-    elif pmax < longest:
-        raise ValueError(f"pmax={pmax} < longest candidate path ({longest} links)")
+    pmax = max((len(p) - 1 for ps in all_paths for p in ps[:k]), default=1)
     idx = np.full((rows, k, pmax), L, dtype=np.int32)
     for i, ps in enumerate(all_paths):
         for kk, path in enumerate(ps[:k]):

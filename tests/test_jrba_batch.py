@@ -59,8 +59,8 @@ def test_batch_handles_mixed_sizes_and_empty_instances():
     net = NETS["edge-mesh"]()
     sets = _flow_sets(net, 2, 3) + [[]] + _flow_sets(net, 2, 10, seed=7)
     sets.append([Flow(2, 2, 5.0)])  # colocated-only instance
-    # dense mode pins the historical bucketing contract (sparse adds
-    # pmax/active-link dimensions to the bucket key — covered in
+    # dense mode pins the historical bucketing contract (sparse adds the
+    # active-link dimension to the bucket key — covered in
     # test_solver_sparse.py)
     eng = JRBAEngine(k=3, n_iters=150, solver="dense")
     out = eng.solve_many(net, sets)
