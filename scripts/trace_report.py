@@ -8,7 +8,11 @@ the file contents (no flag needed):
   * a **Chrome trace** (``benchmarks/fleet.py --trace out.trace.json`` or
     ``Tracer.to_chrome``): a JSON object with a ``traceEvents`` array.
     The report aggregates complete ("X") and matched begin/end ("B"/"E")
-    spans per name, prints the top spans by total duration, a percentile
+    spans per name, prints the top spans by total duration, the host layers
+    the program measures (``lane/step`` with ``sched/alloc`` inside it,
+    ``fleet/pick``, ``fleet/dispatch`` and the engine's ``engine/build``,
+    ``engine/paths``, ``engine/stage``, ``engine/wait``, ``engine/finalize``,
+    ``engine/refine``, plus ``jax/compile`` instants), a percentile
     table for per-job ``job/arrival_to_scheduled`` latencies, the
     barrier-stall attribution (``lane/own_solve`` vs ``lane/barrier_stall``
     totals), a migration digest (``migrate/*`` rounds, commit/reject/
@@ -20,7 +24,9 @@ the file contents (no flag needed):
     (commit/reject/infeasible splits and transfer-penalty totals) when a
     lane ran with a stall budget, and, when the summary carries the
     ``latency`` observability block, the event-latency percentiles, per-lane
-    stall table and solver phase split.
+    stall table and solver phase split, the ``host`` split of each pass
+    (lane steps, Algorithm 1, ``solve_many``, driver) and the relaxation's
+    rounding wins.
 
 Pure stdlib (json/argparse/math) so it runs in the minimal CI environment.
 Exit status 0 on success, 1 on unreadable or empty input.
@@ -68,6 +74,22 @@ def _percentile_row(vals: list[float]) -> str:
 # -- Chrome trace -------------------------------------------------------------
 
 
+# the spans the program measures around its own work, by layer (a fleet
+# lane's event/* spans are split at its yields: each piece is self time)
+LAYER_SPANS = (
+    ("lane/step", "lane stepper resumes (event loop)"),
+    ("sched/alloc", "  Algorithm 1 inside them"),
+    ("fleet/pick", "fleet driver: queue picks, bookkeeping"),
+    ("fleet/dispatch", "fleet driver: solve_many calls"),
+    ("engine/build", "  program build"),
+    ("engine/paths", "    Yen's paths (cache misses)"),
+    ("engine/stage", "  relaxation staging (stack, upload)"),
+    ("engine/wait", "  relaxation launch -> results on host"),
+    ("engine/finalize", "  rounding, Eq. 15"),
+    ("engine/refine", "    start portfolio"),
+)
+
+
 def report_chrome(doc: dict, *, top: int) -> int:
     events = doc.get("traceEvents", [])
     tracks: dict[int, str] = {}
@@ -81,6 +103,7 @@ def report_chrome(doc: dict, *, top: int) -> int:
     track_busy: dict[int, float] = {}
     instants: dict[str, int] = {}
     migrate_args: dict[str, list[dict]] = {}
+    compile_args: list[dict] = []
     open_b: dict[tuple[int, str], list[float]] = {}
     unbalanced = 0
     for ev in events:
@@ -105,6 +128,8 @@ def report_chrome(doc: dict, *, top: int) -> int:
             instants[name] = instants.get(name, 0) + 1
             if name.startswith("migrate/"):
                 migrate_args.setdefault(name, []).append(ev.get("args") or {})
+            elif name == "jax/compile":
+                compile_args.append(ev.get("args") or {})
     unbalanced += sum(len(s) for s in open_b.values())
 
     n_spans = sum(len(v) for v in durs.values())
@@ -121,6 +146,17 @@ def report_chrome(doc: dict, *, top: int) -> int:
                 f"  {name:<28s} {_fmt_s(total)} total"
                 f"  n={len(vals):<6d} mean={_fmt_s(total / len(vals)).strip()}"
             )
+
+    if any(name in durs for name, _ in LAYER_SPANS):
+        print("\nhost layers (measured spans):")
+        for name, what in LAYER_SPANS:
+            vals = durs.get(name)
+            if vals:
+                print(f"  {what:<40s} {name:<16s} {_fmt_s(sum(vals))}  n={len(vals)}")
+    if compile_args:
+        n = sum(int(a.get("compiles") or 0) for a in compile_args)
+        secs = sum(float(a.get("duration_s") or 0.0) for a in compile_args)
+        print(f"\nbackend compiles: {n}, {_fmt_s(secs).strip()}")
 
     jobs = durs.get("job/arrival_to_scheduled")
     if jobs:
@@ -245,6 +281,21 @@ def report_jsonl(lines: list[dict], *, top: int) -> int:
             print("\nsolver phases:")
             for key, val in sorted(phases.items(), key=lambda kv: -kv[1]):
                 print(f"  {key:<20s} {_fmt_s(val)}")
+        host = summary.get("host")
+        if host:
+            print(
+                f"\nhost: lane steps {_fmt_s(host['lane_step_seconds']).strip()} "
+                f"(Algorithm 1 {_fmt_s(host['alloc_seconds']).strip()}, "
+                f"{host['alloc_calls']} calls), "
+                f"solve_many {_fmt_s(host['solve_many_seconds']).strip()}, "
+                f"driver {_fmt_s(host['driver_seconds']).strip()}"
+            )
+        solver = summary.get("solver") or {}
+        if solver.get("refined_programs"):
+            print(
+                f"rounding: the relaxation's start won {solver['relax_start_wins']} "
+                f"of {solver['refined_programs']} programs"
+            )
     return 0
 
 

@@ -56,6 +56,7 @@ import numpy as np
 
 from .graph import Flow, NetworkGraph
 from .paths import k_shortest_paths, path_link_index, path_links
+from ..obs import compiles
 from ..obs.trace import NULL_TRACER
 
 __all__ = [
@@ -424,16 +425,135 @@ def _solve_sparse_batched(
     return jax.nn.softmax(logits + mask, axis=-1), span, steps
 
 
-def solve_relaxation(prog: FlowProgram, *, n_iters: int = 400) -> tuple[np.ndarray, float]:
-    """Solve P3-RELAX-CVX; returns (m_i^k = V_i w_i^k, relaxed span TH*)."""
-    w, span = _solve_md(
+# ---------------------------------------------------------------------------
+# The relaxation call: operands staged (what each solver uploads), the solver
+# launched, its results waited for on the host — timed and counted the same
+# way in every solve function when a caller passes its stats
+# ---------------------------------------------------------------------------
+class _Relaxing:
+    """``with`` block around one relaxation call in a solve function: from
+    entry to :meth:`launching` the ``engine/stage`` span (host stacking and
+    upload), from there to exit the ``engine/wait`` span (launch to results
+    on the host), both on ``track``. On exit ``stats`` (an
+    :class:`EngineStats`, or None) receives both times and the backend
+    compiles made inside, which an enabled ``tracer`` also marks as one
+    ``jax/compile`` instant. The solve functions call the jitted solver from
+    their own frame, not through a helper: on a TPU v5 lite, one frame more
+    between them and the solver made lowering the kernel 1.6-2.6 s slower
+    over the benchmark's 54 warm-up shapes (the Mosaic body keeps the
+    callers' locations)."""
+
+    __slots__ = ("_stats", "_tracer", "_track", "_c0", "_t0", "_t1")
+
+    def __init__(self, stats, tracer, track: str) -> None:
+        self._stats = stats
+        self._tracer = tracer
+        self._track = track
+
+    def __enter__(self) -> "_Relaxing":
+        self._c0 = compiles.snapshot()
+        self._t1 = None
+        self._t0 = time.perf_counter()
+        self._tracer.begin("engine/stage", track=self._track, cat="engine")
+        return self
+
+    def launching(self) -> None:
+        self._tracer.end("engine/stage", track=self._track)
+        self._t1 = time.perf_counter()
+        self._tracer.begin("engine/wait", track=self._track, cat="engine")
+
+    def __exit__(self, *exc) -> bool:
+        tracer, track, t1 = self._tracer, self._track, self._t1
+        tracer.end("engine/stage" if t1 is None else "engine/wait", track=track)
+        stats = self._stats
+        if stats is None or t1 is None:
+            return False
+        stats.stage_seconds += t1 - self._t0
+        stats.wait_seconds += time.perf_counter() - t1
+        new = compiles.snapshot() - self._c0
+        if new.compiles or new.cache_hits:
+            stats.backend_compiles += new.compiles
+            stats.compile_seconds += new.seconds
+            stats.persistent_cache_hits += new.cache_hits
+            if tracer.enabled:
+                tracer.instant(
+                    "jax/compile",
+                    track=track,
+                    cat="compile",
+                    compiles=new.compiles,
+                    duration_s=new.seconds,
+                )
+        return False
+
+
+def _dense_operands(prog: FlowProgram) -> tuple:
+    """One program's dense operands: device-memoized solve-invariant tensors,
+    capacity uploaded fresh."""
+    return (
         prog.device("usage"),
         prog.device("valid"),
         prog.device("volumes"),
         jnp.asarray(prog.capacity),
-        n_iters=n_iters,
     )
-    w, span = jax.device_get((w, span))
+
+
+def _dense_batch_operands(progs: list[FlowProgram]) -> tuple:
+    """Host-side stack + one upload per operand: stacking device-resident
+    mirrors costs a dispatch per operand, which for these small tensors is
+    slower than the copy (the device memo pays off on the scalar paths)."""
+    shapes = {p.usage.shape for p in progs}
+    if len(shapes) != 1:
+        raise ValueError(f"programs span multiple shape buckets: {sorted(shapes)}")
+    return tuple(
+        jnp.asarray(np.stack([getattr(p, name) for p in progs]))
+        for name in ("usage", "valid", "volumes", "capacity")
+    )
+
+
+def _sparse_operands(prog: FlowProgram) -> tuple:
+    """One program's sparse operands as a batch of one: device-memoized
+    solve-invariant tensors, only capacity uploaded per solve."""
+    cap_a = jnp.asarray(prog.capacity_active())
+    n_out = jnp.float32(len(prog.capacity) - prog.la_pad)
+    return (
+        prog.device("usage_active")[None],
+        prog.device("valid")[None],
+        prog.device("volumes")[None],
+        cap_a[None],
+        n_out[None],
+    )
+
+
+def _sparse_batch_operands(progs: list[FlowProgram]) -> tuple:
+    """Same-bucket programs' sparse operands, host-stacked and uploaded once
+    per operand (see :func:`_dense_batch_operands`)."""
+    shapes = {(p.valid.shape, p.la_pad) for p in progs}
+    if len(shapes) != 1:
+        raise ValueError(f"programs span multiple sparse buckets: {sorted(shapes)}")
+    return (
+        jnp.asarray(np.stack([p.usage_active for p in progs])),
+        jnp.asarray(np.stack([p.valid for p in progs])),
+        jnp.asarray(np.stack([p.volumes for p in progs])),
+        jnp.asarray(np.stack([p.capacity_active() for p in progs])),
+        jnp.asarray(np.array([len(p.capacity) - p.la_pad for p in progs], dtype=np.float32)),
+    )
+
+
+def solve_relaxation(
+    prog: FlowProgram,
+    *,
+    n_iters: int = 400,
+    stats: EngineStats | None = None,
+    tracer=NULL_TRACER,
+    track: str = "engine",
+) -> tuple[np.ndarray, float]:
+    """Solve P3-RELAX-CVX; returns (m_i^k = V_i w_i^k, relaxed span TH*).
+    ``stats``/``tracer``/``track``: see :class:`_Relaxing` (every solve
+    function takes them; the engine passes its own)."""
+    with _Relaxing(stats, tracer, track) as clock:
+        ops = _dense_operands(prog)
+        clock.launching()
+        w, span = jax.device_get(_solve_md(*ops, n_iters=n_iters))
     m = np.asarray(w) * prog.volumes[:, None]
     return m, float(span)
 
@@ -457,6 +577,9 @@ def solve_relaxation_sparse(
     stable_chunks: int = 2,
     backend: str = "jnp",
     interpret: bool = False,
+    stats: EngineStats | None = None,
+    tracer=NULL_TRACER,
+    track: str = "engine",
 ) -> tuple[np.ndarray, float, int]:
     """Sparse solve of one program; returns ``(m, relaxed_span, steps)``.
 
@@ -466,22 +589,20 @@ def solve_relaxation_sparse(
     solve-invariant tensors — only capacity is uploaded per solve. The
     B == 1 lane of the batched solver IS the scalar path, so scalar and
     batched solves share one compiled structure."""
-    cap_a = jnp.asarray(prog.capacity_active())
-    n_out = jnp.float32(len(prog.capacity) - prog.la_pad)
-    w, span, steps = _sparse_solver(backend, interpret)(
-        prog.device("usage_active")[None],
-        prog.device("valid")[None],
-        prog.device("volumes")[None],
-        cap_a[None],
-        n_out[None],
-        n_iters=n_iters,
-        early_exit=early_exit,
-        span_rtol=span_rtol,
-        stable_chunks=stable_chunks,
-    )
-    w, span, steps = jax.device_get((w[0], span[0], steps[0]))
-    m = np.asarray(w) * prog.volumes[:, None]
-    return m, float(span), int(steps)
+    with _Relaxing(stats, tracer, track) as clock:
+        ops = _sparse_operands(prog)
+        clock.launching()
+        w, span, steps = jax.device_get(
+            _sparse_solver(backend, interpret)(
+                *ops,
+                n_iters=n_iters,
+                early_exit=early_exit,
+                span_rtol=span_rtol,
+                stable_chunks=stable_chunks,
+            )
+        )
+    m = np.asarray(w[0]) * prog.volumes[:, None]
+    return m, float(span[0]), int(steps[0])
 
 
 def solve_relaxation_sparse_batch(
@@ -493,32 +614,25 @@ def solve_relaxation_sparse_batch(
     stable_chunks: int = 2,
     backend: str = "jnp",
     interpret: bool = False,
+    stats: EngineStats | None = None,
+    tracer=NULL_TRACER,
+    track: str = "engine",
 ) -> list[tuple[np.ndarray, float, int]]:
     """Sparse twin of :func:`solve_relaxation_batch`; one vmapped (or
     Pallas-gridded) dispatch for N same-shape programs, one device sync for
     all results. Programs must share the (Nf, K, La_pad) bucket."""
-    shapes = {(p.valid.shape, p.la_pad) for p in progs}
-    if len(shapes) != 1:
-        raise ValueError(f"programs span multiple sparse buckets: {sorted(shapes)}")
-    # host-side stack + one upload per operand (see solve_relaxation_batch)
-    valid = jnp.asarray(np.stack([p.valid for p in progs]))
-    volumes = jnp.asarray(np.stack([p.volumes for p in progs]))
-    cap_a = jnp.asarray(np.stack([p.capacity_active() for p in progs]))
-    n_out = jnp.asarray(
-        np.array([len(p.capacity) - p.la_pad for p in progs], dtype=np.float32)
-    )
-    w, spans, steps = _sparse_solver(backend, interpret)(
-        jnp.asarray(np.stack([p.usage_active for p in progs])),
-        valid,
-        volumes,
-        cap_a,
-        n_out,
-        n_iters=n_iters,
-        early_exit=early_exit,
-        span_rtol=span_rtol,
-        stable_chunks=stable_chunks,
-    )
-    w, spans, steps = jax.device_get((w, spans, steps))
+    with _Relaxing(stats, tracer, track) as clock:
+        ops = _sparse_batch_operands(progs)
+        clock.launching()
+        w, spans, steps = jax.device_get(
+            _sparse_solver(backend, interpret)(
+                *ops,
+                n_iters=n_iters,
+                early_exit=early_exit,
+                span_rtol=span_rtol,
+                stable_chunks=stable_chunks,
+            )
+        )
     return [
         (np.asarray(w[i]) * p.volumes[:, None], float(spans[i]), int(steps[i]))
         for i, p in enumerate(progs)
@@ -526,27 +640,22 @@ def solve_relaxation_sparse_batch(
 
 
 def solve_relaxation_batch(
-    progs: list[FlowProgram], *, n_iters: int = 400
+    progs: list[FlowProgram],
+    *,
+    n_iters: int = 400,
+    stats: EngineStats | None = None,
+    tracer=NULL_TRACER,
+    track: str = "engine",
 ) -> list[tuple[np.ndarray, float]]:
     """Solve N same-shape programs in one vmapped call.
 
     All programs must already be padded to a common (Nf, K, L) bucket (the
     engine guarantees this); raises on shape mismatch rather than silently
     re-padding, so callers control bucketing policy."""
-    shapes = {p.usage.shape for p in progs}
-    if len(shapes) != 1:
-        raise ValueError(f"programs span multiple shape buckets: {sorted(shapes)}")
-    # host-side stack + one upload per operand: stacking device-resident
-    # mirrors costs a dispatch per operand, which for these small tensors is
-    # slower than the copy (the device memo pays off on the scalar paths)
-    w, spans = _solve_md_batched(
-        jnp.asarray(np.stack([p.usage for p in progs])),
-        jnp.asarray(np.stack([p.valid for p in progs])),
-        jnp.asarray(np.stack([p.volumes for p in progs])),
-        jnp.asarray(np.stack([p.capacity for p in progs])),
-        n_iters=n_iters,
-    )
-    w, spans = jax.device_get((w, spans))
+    with _Relaxing(stats, tracer, track) as clock:
+        ops = _dense_batch_operands(progs)
+        clock.launching()
+        w, spans = jax.device_get(_solve_md_batched(*ops, n_iters=n_iters))
     return [
         (np.asarray(w[i]) * p.volumes[:, None], float(spans[i]))
         for i, p in enumerate(progs)
@@ -614,6 +723,10 @@ class JRBAResult:
     # batching can accept a stale solve whenever the residual is unchanged on
     # this mask (see OnlineScheduler's repair pass)
     candidate_links: np.ndarray | None = None
+    # relaxation steps this program's own lane ran before its early exit
+    # (the full budget without early exit; 0 on the analytic single-flow
+    # path, which runs no relaxation)
+    relax_steps: int = 0
 
     @property
     def throughput_bound(self) -> float:
@@ -658,7 +771,7 @@ def _rounding_span(prog: FlowProgram, ks: np.ndarray) -> float:
     return float(np.max((sel.T @ prog.volumes) / prog.capacity))
 
 
-def _round_and_refine(prog: FlowProgram, m: np.ndarray) -> np.ndarray:
+def _round_and_refine(prog: FlowProgram, m: np.ndarray, counts=None) -> np.ndarray:
     """Solver-robust rounding: best-response sweeps from a deterministic
     portfolio of starts, with the relaxation's argmax start consulted last.
 
@@ -674,7 +787,11 @@ def _round_and_refine(prog: FlowProgram, m: np.ndarray) -> np.ndarray:
     argmax start win only when *strictly* better. Any all-same-path argmax
     vertex is already in the portfolio, so in the degenerate regime every
     formulation returns the identical (and never worse) solution — the
-    property the churn benchmark asserts as zero record deviation."""
+    property the churn benchmark asserts as zero record deviation.
+
+    ``counts`` (an :class:`EngineStats`) receives the chains swept, their
+    sweeps, and a relaxation-start win when the argmax start's chain is the
+    one returned."""
     Nf, K = prog.valid.shape
     first_valid = np.argmax(prog.valid, axis=1)
     best_ks: np.ndarray | None = None
@@ -687,7 +804,7 @@ def _round_and_refine(prog: FlowProgram, m: np.ndarray) -> np.ndarray:
         if any(np.array_equal(start, s) for s in seen):
             continue  # duplicate start -> identical sweep; skip the chain
         seen.append(start)
-        ks = _best_response_sweeps(prog, start)
+        ks = _best_response_sweeps(prog, start, counts=counts)
         span = _rounding_span(prog, ks)
         if span < best:
             best_ks, best = ks, span
@@ -696,12 +813,16 @@ def _round_and_refine(prog: FlowProgram, m: np.ndarray) -> np.ndarray:
         # the argmax start is one of the portfolio starts (the degenerate
         # all-same-path case): its sweep was already scored into best_ks
         return best_ks
-    ks_w = _best_response_sweeps(prog, start_w)
-    return ks_w if _rounding_span(prog, ks_w) < best else best_ks
+    ks_w = _best_response_sweeps(prog, start_w, counts=counts)
+    if _rounding_span(prog, ks_w) < best:
+        if counts is not None:
+            counts.relax_start_wins += 1
+        return ks_w
+    return best_ks
 
 
 def _best_response_sweeps(
-    prog: FlowProgram, ks: np.ndarray, *, sweeps: int = 5
+    prog: FlowProgram, ks: np.ndarray, *, sweeps: int = 5, counts=None
 ) -> np.ndarray:
     """Vertex-recovery refinement after argmax rounding.
 
@@ -711,11 +832,14 @@ def _best_response_sweeps(
     congested path (e.g. it loses Fig. 2(f)). Best-response sweeps — each
     flow re-picks the path minimizing the resulting congestion with the
     others fixed — monotonically reduce the span and recover vertex quality.
+    ``counts`` receives one chain and the number of sweeps it ran.
     """
     Nf, K, L = prog.usage.shape
     order = np.argsort(-prog.volumes)
     load = prog.usage[np.arange(Nf), ks].T @ prog.volumes  # (L,)
+    done = 0
     for _ in range(sweeps):
+        done += 1
         changed = False
         for i in order:
             load = load - prog.usage[i, ks[i]] * prog.volumes[i]
@@ -729,6 +853,9 @@ def _best_response_sweeps(
             load = load + prog.usage[i, ks[i]] * prog.volumes[i]
         if not changed:
             break
+    if counts is not None:
+        counts.refine_chains += 1
+        counts.refine_sweeps += done
     return ks
 
 
@@ -739,16 +866,18 @@ def _finalize(
     *,
     water_filling: bool = False,
     refine: bool = True,
+    ks: np.ndarray | None = None,
 ) -> JRBAResult:
     """Rounding (k* = argmax), vertex-recovery refinement, Eq. 15 bandwidth
     recovery and the optional water-filling top-up — the host-side half of
     Algorithm 2, shared by the single and batched solve paths. With
     ``refine`` the rounding runs through the start-portfolio refinement
     (:func:`_round_and_refine`), which is deterministic across solver
-    formulations on degenerate symmetric programs."""
-    if refine:
+    formulations on degenerate symmetric programs; ``ks`` passes in a
+    rounding the caller already made."""
+    if ks is None and refine:
         ks = _round_and_refine(prog, m)
-    else:
+    elif ks is None:
         ks = np.argmax(np.where(prog.valid, m, -1.0), axis=1)  # k* = argmax_k m_i^k
     n = prog.n_real  # drop shape-padding dummies
     sel_usage = prog.usage[np.arange(n), ks[:n]]  # (n_real, L)
@@ -815,12 +944,14 @@ def jrba(
 @dataclasses.dataclass
 class EngineStats:
     """Observability for the solver cache (`hits`/`misses` count shape-bucket
-    signatures: a miss triggers an XLA trace+compile, a hit reuses it) and
-    the convergence-adaptive sparse solver (per-lane semantic steps vs the
-    fixed budget the dense schedule would have burned — a lockstep batch's
-    device work is governed by its slowest live lane, and batch-padding
-    lanes are excluded; ``fast_path_solves`` are single-flow programs
-    rounded host-side with no relaxation at all)."""
+    signatures the engine keys: a miss normally means an XLA trace+compile,
+    which ``backend_compiles`` counts whatever the shape key), the
+    convergence-adaptive sparse solver (per-lane semantic steps vs the fixed
+    budget the dense schedule would have burned — a lockstep batch's device
+    work is governed by its slowest live lane, and batch-padding lanes are
+    excluded; ``fast_path_solves`` are single-flow programs rounded
+    host-side with no relaxation at all), and the work inside each phase.
+    Every field is a number, so two snapshots subtract."""
 
     single_solves: int = 0
     batched_solves: int = 0  # compiled batch calls
@@ -839,6 +970,27 @@ class EngineStats:
     cache_seconds: float = 0.0  # program-cache hits: capacity-only replay
     dispatch_seconds: float = 0.0  # jitted relaxation calls (device dispatch)
     finalize_seconds: float = 0.0  # host rounding / refine / water-filling
+    # inside the phases above, each measured around its own work (and drawn
+    # as the engine/* span of the same name): operand stacking + upload and
+    # the launch-to-device_get wait split dispatch; the start portfolio of
+    # _round_and_refine sits inside finalize; Yen's enumeration on path-cache
+    # misses sits inside build, or in candidate_links outside every phase
+    stage_seconds: float = 0.0
+    wait_seconds: float = 0.0
+    refine_seconds: float = 0.0
+    refined_programs: int = 0  # programs rounded through the start portfolio
+    refine_chains: int = 0  # best-response chains swept
+    refine_sweeps: int = 0  # best-response passes over a program's flows
+    relax_start_wins: int = 0  # the relaxation's argmax start strictly won
+    paths_seconds: float = 0.0
+    paths_computed: int = 0  # (src, dst) pairs enumerated by Yen's algorithm
+    # backend compiles made during this engine's relaxation calls, where all
+    # of its JAX work runs (jax.monitoring via repro.obs.compiles, so shapes
+    # outside the engine's key model count too), their seconds, and how many
+    # of them the persistent cache answered
+    backend_compiles: int = 0
+    compile_seconds: float = 0.0
+    persistent_cache_hits: int = 0
     solver_steps: int = 0  # relaxation steps actually run (early exit counted)
     solver_step_budget: int = 0  # n_iters * relaxation solves (the dense cost)
     fast_path_solves: int = 0  # single-flow programs solved analytically
@@ -932,6 +1084,9 @@ class JRBAEngine:
         self._topo_seen: "weakref.WeakKeyDictionary[NetworkGraph, int]" = (
             weakref.WeakKeyDictionary()
         )
+        # per-program relaxation steps of the last relaxation this engine ran
+        self._steps: list[int] = []
+        compiles.listen()
 
     def bucket(self, n_real: int) -> int:
         """Smallest power-of-two bucket (>= min_bucket) holding n_real rows."""
@@ -983,6 +1138,12 @@ class JRBAEngine:
         *,
         capacity: np.ndarray | None = None,
     ) -> FlowProgram | None:
+        with self.tracer.span("engine/build", track=self.trace_track, cat="engine"):
+            return self._build(net, flows, capacity)
+
+    def _build(
+        self, net: NetworkGraph, flows: list[Flow], capacity: np.ndarray | None
+    ) -> FlowProgram | None:
         # mirror build_program's flow filter so the bucket is known up front
         # and the program is built exactly once
         t0 = time.perf_counter()
@@ -1006,9 +1167,9 @@ class JRBAEngine:
             out = dataclasses.replace(ent, capacity=cap, flows=kept)
             self.stats.cache_seconds += time.perf_counter() - t0
             return out
-        paths = self._paths.get(net)
-        if paths is None:
-            paths = self._paths.setdefault(net, {})
+        paths = self._path_cache(net)
+        for f in kept:
+            self._candidate_paths(net, paths, f.src, f.dst)
         prog = build_program(
             net,
             flows,
@@ -1103,20 +1264,33 @@ class JRBAEngine:
         repair pass uses it to decide which queued speculations an admission
         can invalidate."""
         self._check_topology(net)
-        cache = self._paths.get(net)
-        if cache is None:
-            cache = self._paths.setdefault(net, {})
+        cache = self._path_cache(net)
         mask = np.zeros(len(net.links), dtype=bool)
         for f in flows:
             if f.src == f.dst or f.volume <= 0:
                 continue
-            key = (f.src, f.dst, self.k)
-            ps = cache.get(key)
-            if ps is None:
-                ps = cache[key] = k_shortest_paths(net, f.src, f.dst, self.k)
-            for path in ps:
+            for path in self._candidate_paths(net, cache, f.src, f.dst):
                 mask[path_links(net, path)] = True
         return mask
+
+    def _path_cache(self, net: NetworkGraph) -> dict:
+        cache = self._paths.get(net)
+        if cache is None:
+            cache = self._paths.setdefault(net, {})
+        return cache
+
+    def _candidate_paths(self, net: NetworkGraph, cache: dict, src: int, dst: int) -> list:
+        """The k candidate paths of ``src -> dst`` from ``net``'s path cache,
+        enumerated (and timed) on a miss."""
+        key = (src, dst, self.k)
+        ps = cache.get(key)
+        if ps is None:
+            t0 = time.perf_counter()
+            with self.tracer.span("engine/paths", track=self.trace_track, cat="engine"):
+                ps = cache[key] = k_shortest_paths(net, src, dst, self.k)
+            self.stats.paths_seconds += time.perf_counter() - t0
+            self.stats.paths_computed += 1
+        return ps
 
     def _use_fast_path(self, prog: FlowProgram, refine: bool) -> bool:
         return self.solver != "dense" and refine and prog.n_real == 1
@@ -1130,26 +1304,38 @@ class JRBAEngine:
         span (the LP could split traffic lower; nothing downstream consumes
         the certificate)."""
         m0 = np.where(prog.valid, prog.volumes[:, None], -1.0)
-        res = _finalize(prog, m0, 0.0, water_filling=water_filling, refine=True)
+        res = self._finalize_one(prog, m0, 0.0, water_filling, True, 0)
         res.relaxed_span = res.span
         self.stats.fast_path_solves += 1
         return res
 
-    def _relax_one(self, prog: FlowProgram) -> tuple[np.ndarray, float]:
-        """Solver-mode dispatch for one program (stats included)."""
-        if self.solver == "dense":
-            m, relaxed = solve_relaxation(prog, n_iters=self.n_iters)
-            steps = self.n_iters
-        else:
-            m, relaxed, steps = solve_relaxation_sparse(
-                prog,
-                n_iters=self.n_iters,
+    def _solver_options(self) -> dict:
+        """The engine's solver settings and observers, as the solve
+        functions take them."""
+        opts = {
+            "n_iters": self.n_iters,
+            "stats": self.stats,
+            "tracer": self.tracer,
+            "track": self.trace_track,
+        }
+        if self.solver != "dense":
+            opts.update(
                 early_exit=self.early_exit,
                 span_rtol=self.span_rtol,
                 stable_chunks=self.stable_chunks,
                 backend="pallas" if self.solver.startswith("pallas") else "jnp",
                 interpret=self.solver == "pallas-interpret",
             )
+        return opts
+
+    def _relax_one(self, prog: FlowProgram) -> tuple[np.ndarray, float]:
+        """Solver-mode dispatch for one program (stats included)."""
+        if self.solver == "dense":
+            m, relaxed = solve_relaxation(prog, **self._solver_options())
+            steps = self.n_iters
+        else:
+            m, relaxed, steps = solve_relaxation_sparse(prog, **self._solver_options())
+        self._steps = [steps]
         self.stats.solver_steps += steps
         self.stats.solver_step_budget += self.n_iters
         return m, relaxed
@@ -1157,29 +1343,44 @@ class JRBAEngine:
     def _relax_group(
         self, progs: list[FlowProgram], n_real: int | None = None
     ) -> list[tuple[np.ndarray, float]]:
-        """Solver-mode dispatch for one same-bucket batch (stats included).
+        """One compiled call for a same-shape group (stats included).
         ``n_real`` excludes batch-dimension padding lanes (repeats of the
         last program) from the step counters; note the per-lane step counts
         are the *semantic* early-exit points — a lockstep batch's device
-        work is governed by its slowest live lane."""
+        work is governed by its slowest live lane. Each program's own step
+        count is left in ``_steps``."""
         n_real = len(progs) if n_real is None else n_real
         if self.solver == "dense":
-            solved = solve_relaxation_batch(progs, n_iters=self.n_iters)
-            self.stats.solver_steps += self.n_iters * n_real
-            self.stats.solver_step_budget += self.n_iters * n_real
-            return solved
-        solved3 = solve_relaxation_sparse_batch(
-            progs,
-            n_iters=self.n_iters,
-            early_exit=self.early_exit,
-            span_rtol=self.span_rtol,
-            stable_chunks=self.stable_chunks,
-            backend="pallas" if self.solver.startswith("pallas") else "jnp",
-            interpret=self.solver == "pallas-interpret",
-        )
-        self.stats.solver_steps += sum(s for _, _, s in solved3[:n_real])
+            solved = solve_relaxation_batch(progs, **self._solver_options())
+            self._steps = [self.n_iters] * len(progs)
+        else:
+            solved3 = solve_relaxation_sparse_batch(progs, **self._solver_options())
+            solved = [(m, relaxed) for m, relaxed, _ in solved3]
+            self._steps = [steps for _, _, steps in solved3]
+        self.stats.solver_steps += sum(self._steps[:n_real])
         self.stats.solver_step_budget += self.n_iters * n_real
-        return [(m, relaxed) for m, relaxed, _ in solved3]
+        return solved
+
+    def _finalize_one(
+        self,
+        prog: FlowProgram,
+        m: np.ndarray,
+        relaxed: float,
+        water_filling: bool,
+        refine: bool,
+        steps: int,
+    ) -> JRBAResult:
+        """:func:`_finalize` with the start portfolio timed and counted."""
+        ks = None
+        if refine:
+            t0 = time.perf_counter()
+            with self.tracer.span("engine/refine", track=self.trace_track, cat="engine"):
+                ks = _round_and_refine(prog, m, self.stats)
+            self.stats.refine_seconds += time.perf_counter() - t0
+            self.stats.refined_programs += 1
+        res = _finalize(prog, m, relaxed, water_filling=water_filling, refine=refine, ks=ks)
+        res.relax_steps = steps
+        return res
 
     def solve(
         self,
@@ -1194,9 +1395,11 @@ class JRBAEngine:
         prog = self.build(net, flows, capacity=capacity)
         if prog is None:
             return None
+        tracer, track = self.tracer, self.trace_track
         if self._use_fast_path(prog, refine):
             t0 = time.perf_counter()
-            res = self._fast_single(prog, water_filling)
+            with tracer.span("engine/finalize", track=track, cat="engine"):
+                res = self._fast_single(prog, water_filling)
             dt = time.perf_counter() - t0
             self.stats.solve_seconds += dt
             self.stats.finalize_seconds += dt
@@ -1208,13 +1411,9 @@ class JRBAEngine:
         self.stats.solve_seconds += dt
         self.stats.dispatch_seconds += dt
         self.stats.single_solves += 1
-        tracer = self.tracer
-        if tracer.enabled:
-            tracer.complete(
-                "engine/relax", track=self.trace_track, cat="engine", ts=tracer.now() - dt, dur=dt
-            )
         t0 = time.perf_counter()
-        res = _finalize(prog, m, relaxed, water_filling=water_filling, refine=refine)
+        with tracer.span("engine/finalize", track=track, cat="engine"):
+            res = self._finalize_one(prog, m, relaxed, water_filling, refine, self._steps[0])
         self.stats.finalize_seconds += time.perf_counter() - t0
         return res
 
@@ -1267,18 +1466,19 @@ class JRBAEngine:
         ]
         results: list[JRBAResult | None] = [None] * n
         by_bucket: dict[tuple, list[int]] = {}
+        tracer, track = self.tracer, self.trace_track
         for i, p in enumerate(progs):
             if p is None:
                 continue
             if self._use_fast_path(p, refine):
                 t0 = time.perf_counter()
-                results[i] = self._fast_single(p, wf[i])
+                with tracer.span("engine/finalize", track=track, cat="engine"):
+                    results[i] = self._fast_single(p, wf[i])
                 dt = time.perf_counter() - t0
                 self.stats.solve_seconds += dt
                 self.stats.finalize_seconds += dt
             else:
                 by_bucket.setdefault(self._shape_key(p), []).append(i)
-        tracer = self.tracer
         for shape, idxs in by_bucket.items():
             group = [progs[i] for i in idxs]
             b_pad = 1
@@ -1290,27 +1490,18 @@ class JRBAEngine:
             self._note_shape(("batch", b_pad, shape, self.n_iters))
             padded = group + [group[-1]] * (b_pad - len(group))
             t0 = time.perf_counter()
+            self._steps = []
             solved = self._relax_group(padded, n_real=len(group))[: len(group)]
+            steps = self._steps or [0] * len(group)
             dt = time.perf_counter() - t0
             self.stats.solve_seconds += dt
             self.stats.dispatch_seconds += dt
             self.stats.batched_solves += 1
             self.stats.batched_instances += len(group)
-            if tracer.enabled:
-                tracer.complete(
-                    "engine/batch",
-                    track=self.trace_track,
-                    cat="engine",
-                    ts=tracer.now() - dt,
-                    dur=dt,
-                    instances=len(group),
-                    batch_pad=b_pad,
-                )
             t0 = time.perf_counter()
-            for i, prog, (m, relaxed) in zip(idxs, group, solved):
-                results[i] = _finalize(
-                    prog, m, relaxed, water_filling=wf[i], refine=refine
-                )
+            with tracer.span("engine/finalize", track=track, cat="engine"):
+                for i, prog, (m, relaxed), st in zip(idxs, group, solved, steps):
+                    results[i] = self._finalize_one(prog, m, relaxed, wf[i], refine, st)
             self.stats.finalize_seconds += time.perf_counter() - t0
         return results
 

@@ -211,6 +211,10 @@ class SimResult:
     migration_penalty_seconds: float = 0.0
     migration_spec_accepted: int = 0
     migration_spec_repaired: int = 0
+    # Algorithm 1 (OnlineScheduler._allocate): wall-clock and calls, replays
+    # of a recorded speculative allocation not included
+    alloc_seconds: float = 0.0
+    alloc_calls: int = 0
 
     @property
     def migration_commit_rate(self) -> float:
@@ -289,7 +293,10 @@ class RoundRequest:
     yields one of these; the driver answers via
     ``gen.send((results, seconds))`` where ``results`` aligns with ``solves``
     (``None`` entries for empty programs) and ``seconds`` is the solver
-    wall-clock to attribute to this simulation's ``sched_overhead``.
+    wall-clock to attribute to this simulation's ``sched_overhead``. A fleet
+    driver may append the index of the dispatch that answered the round,
+    ``(results, seconds, dispatch)``; the stepper's ``sched/solve`` span
+    carries it.
 
     Most suspension points carry a single solve (an OTFA refresh, a
     sequential-OTFS admission, a repair re-solve); a speculative OTFS round
@@ -313,7 +320,8 @@ class RoundRequest:
     solves: list[SolveRequest]
 
 
-RoundReply = tuple[list[JRBAResult | None], float]  # (solutions, wall-clock)
+# (solutions, wall-clock[, answering dispatch index])
+RoundReply = tuple[list[JRBAResult | None], float] | tuple[list[JRBAResult | None], float, int]
 
 
 @dataclasses.dataclass
@@ -429,9 +437,22 @@ class OnlineScheduler:
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.metrics = metrics if metrics is not None else NULL_METRICS
         self.trace_track = "sim"
+        # Algorithm 1's running totals; step() reports each run's share
+        self.alloc_seconds = 0.0
+        self.alloc_calls = 0
 
     # -- per-policy allocation ----------------------------------------------
     def _allocate(self, job: JobGraph, job_id: int) -> tuple[Allocation, list[Flow]]:
+        """Algorithm 1 (or the LR/BR baselines) for one job, timed as the
+        ``sched/alloc`` span here, so a subclass's work around it is not."""
+        t0 = time.perf_counter()
+        with self.tracer.span("sched/alloc", track=self.trace_track, cat="alloc"):
+            out = self._place(job, job_id)
+        self.alloc_seconds += time.perf_counter() - t0
+        self.alloc_calls += 1
+        return out
+
+    def _place(self, job: JobGraph, job_id: int) -> tuple[Allocation, list[Flow]]:
         if self.base == "LR":
             return allocate_whole_job_lr(self.net, job, job_id=job_id)
         if self.base == "BR":
@@ -582,6 +603,7 @@ class OnlineScheduler:
         migration_penalty_seconds = 0.0
         migration_spec_accepted = migration_spec_repaired = 0
         migrate_on = self.stall_budget is not None  # __init__ pinned base=OTFS
+        alloc0, alloc_calls0 = self.alloc_seconds, self.alloc_calls
 
         def solve_round(reqs: list[SolveRequest]):
             """Sub-generator wrapping every driver suspension: yields one
@@ -590,7 +612,8 @@ class OnlineScheduler:
             nonlocal sched_overhead, n_dispatches, n_solves
             for s in reqs:
                 s.bucket = self.engine.bucket_key(s.net, s.flows)
-            results, dt = yield RoundRequest(reqs)
+            reply = yield RoundRequest(reqs)
+            results, dt = reply[0], reply[1]
             sched_overhead += dt
             n_dispatches += 1
             n_solves += len(reqs)
@@ -605,6 +628,7 @@ class OnlineScheduler:
                     ts=tracer.now() - dt,
                     dur=dt,
                     n_solves=len(reqs),
+                    dispatch=reply[2] if len(reply) > 2 else None,
                 )
             return results
 
@@ -1567,4 +1591,6 @@ class OnlineScheduler:
             migration_penalty_seconds=migration_penalty_seconds,
             migration_spec_accepted=migration_spec_accepted,
             migration_spec_repaired=migration_spec_repaired,
+            alloc_seconds=self.alloc_seconds - alloc0,
+            alloc_calls=self.alloc_calls - alloc_calls0,
         )

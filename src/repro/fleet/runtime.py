@@ -53,7 +53,7 @@ from ..core.online import (
 )
 from ..core.scenarios import SCENARIOS, ChurnStep, capacity_drift_trace
 from ..obs.metrics import MetricsRegistry, StreamingHistogram
-from ..obs.trace import NULL_TRACER, Tracer
+from ..obs.trace import NULL_SPAN, NULL_TRACER, Tracer
 from .telemetry import DispatchRecord, FleetTelemetry, RoundRecord
 
 __all__ = [
@@ -205,6 +205,7 @@ class _Lane:
     idx: int = 0  # position in the fleet (indexes the per-lane stall arrays)
     pending: RoundRequest | None = None
     result: SimResult | None = None
+    step_seconds: float = 0.0  # wall-clock inside this lane's stepper
 
 
 @dataclasses.dataclass
@@ -220,6 +221,7 @@ class _InFlight:
     remaining: int
     enqueue_ts: float  # wall clock when the round was enqueued
     own_seconds: float = 0.0  # this round's amortized share of its dispatches
+    dispatch: int = -1  # index of the latest dispatch this round rode
 
 
 @dataclasses.dataclass
@@ -230,6 +232,47 @@ class _QueueEntry:
     pos: int  # index into inflight.solves / .results
     seq: int  # global enqueue order (cross-bucket age tie-break)
     ts: float  # wall clock at enqueue (deadline + queue-wait measurement)
+
+
+class _Stopwatch:
+    """A reusable ``with`` block that adds its wall-clock to ``seconds`` and
+    draws each use as a span ``name`` on the tracer's ``fleet`` track: the
+    driver's own work, timed where it happens."""
+
+    __slots__ = ("seconds", "_tracer", "_name", "_span", "_t0")
+
+    def __init__(self, tracer: Tracer, name: str) -> None:
+        self.seconds = 0.0
+        self._tracer = tracer
+        self._name = name
+        self._span = NULL_SPAN
+        self._t0 = 0.0
+
+    def __enter__(self) -> "_Stopwatch":
+        self._t0 = time.perf_counter()
+        self._span = self._tracer.span(self._name, track="fleet", cat="fleet")
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._span.__exit__(*exc)
+        self.seconds += time.perf_counter() - self._t0
+        return False
+
+
+def _dispatch_span(tracer: Tracer, dispatch: int, fired_by: str, n_solves: int):
+    """The ``fleet/dispatch`` span around one ``solve_many``; its arguments
+    are built only when a sink records them."""
+    if not tracer.recording:
+        return NULL_SPAN
+    return tracer.span(
+        "fleet/dispatch",
+        track="fleet",
+        cat="fleet",
+        dispatch=dispatch,
+        fired_by=fired_by,
+        n_solves=n_solves,
+    )
 
 
 def _round_has_real_solves(req: RoundRequest) -> bool:
@@ -304,14 +347,18 @@ class FleetRuntime:
     ``latency.queue`` block (fire causes, wait percentiles).
 
     **Tracing / metrics.** Pass ``tracer=repro.obs.Tracer()`` (and/or
-    ``observe=True``) to record per-event spans on one track per lane plus a
-    shared engine track, per-lane barrier (or per-entry ``queue/wait``)
-    intervals, and per-job arrival→scheduled latency histograms (merged per
-    scenario into ``latency.events``). The runtime re-points each lane
-    scheduler's ``tracer``/``metrics``/``trace_track`` and the engine's
-    ``tracer``; with neither flag the schedulers keep their null objects and
-    the run is byte-identical to an unobserved one (the fleet benchmark's
-    ``latency`` section measures the enabled overhead at <5%).
+    ``observe=True``) to record per-event spans on one track per lane, the
+    driver's ``fleet/pick`` and ``fleet/dispatch`` spans on a ``fleet``
+    track, the engine's spans on a shared engine track, per-lane barrier
+    (or per-entry ``queue/wait``) intervals, and per-job arrival→scheduled
+    latency histograms (merged per scenario into ``latency.events``). The
+    runtime re-points each lane scheduler's ``tracer``/``metrics`` and the
+    engine's ``tracer``; with neither flag the schedulers keep their null
+    objects and the run is byte-identical to an unobserved one. Each lane
+    gets its own ``trace_track`` either way, and every resume of its stepper
+    is a ``lane/step`` span, so under a profiler capture each lane's spans
+    nest apart. The pass's host split (``host`` in the summary) and the
+    attribution above are always on.
     """
 
     def __init__(
@@ -359,14 +406,17 @@ class FleetRuntime:
         tracer = self.tracer if self.tracer is not None else NULL_TRACER
         observing = self.observe or tracer.enabled
         lane_metrics: list[MetricsRegistry] | None = None
+        # one timeline track per lane, always: a profiler capture records
+        # the spans of even a null tracer, and each lane's must nest apart
+        for i, s in enumerate(sims):
+            s.scheduler.trace_track = f"lane{i}:{s.name or 'sim'}"
         if observing:
-            # one timeline track + one metrics registry per lane, engine
-            # spans on a shared track; wiring happens before the steppers
-            # are created so step() binds the observed objects
+            # one metrics registry per lane, engine spans on a shared track;
+            # wiring happens before the steppers are created so step() binds
+            # the observed objects
             lane_metrics = [MetricsRegistry() for _ in sims]
             for i, s in enumerate(sims):
                 s.scheduler.tracer = tracer
-                s.scheduler.trace_track = f"lane{i}:{s.name or 'sim'}"
                 s.scheduler.metrics = lane_metrics[i]
             engine.tracer = tracer
         # snapshot so telemetry reports THIS run's cache behaviour even when
@@ -381,6 +431,7 @@ class FleetRuntime:
         ]
         for lane in lanes:  # prime: advance to the first solve (or completion)
             self._advance(lane, None)
+        pick = _Stopwatch(tracer, "fleet/pick")
         if self.mode == "async":
             (
                 lane_own,
@@ -389,14 +440,27 @@ class FleetRuntime:
                 total_dispatch,
                 queue_block,
                 n_requests,
-            ) = self._drive_async(lanes, engine, telemetry, tracer, hits0, misses0)
+            ) = self._drive_async(lanes, engine, telemetry, tracer, pick, hits0, misses0)
         else:
             lane_own, lane_stall, lane_wall, total_dispatch = self._drive_lockstep(
-                lanes, engine, telemetry, tracer, hits0, misses0
+                lanes, engine, telemetry, tracer, pick, hits0, misses0
             )
             queue_block, n_requests = None, None
         wall = time.perf_counter() - t_start
         results = [ln.result for ln in lanes]
+        lane_steps = sum(ln.step_seconds for ln in lanes)
+        host = {
+            # where the pass's host time went, each measured where the work
+            # runs: the lanes' steppers (event loop + Algorithm 1), the
+            # engine (every solve_many), and the driver's fleet/pick blocks
+            # (queue picks, bookkeeping); the three sum to the pass's wall
+            # up to loop overhead
+            "lane_step_seconds": lane_steps,
+            "alloc_seconds": sum(r.alloc_seconds for r in results),
+            "alloc_calls": sum(r.alloc_calls for r in results),
+            "solve_many_seconds": total_dispatch,
+            "driver_seconds": pick.seconds,
+        }
         stats1 = dataclasses.asdict(engine.stats)
         # engine phase breakdown for THIS run: where the flat solve time
         # actually went (host build, cache replay, device dispatch, rounding)
@@ -463,27 +527,37 @@ class FleetRuntime:
             "events": events_block,
             "solver_phases": solver_phases,
         }
+        solver = {
+            "mode": engine.solver,
+            **{
+                key: stats1[key] - solver0[key]
+                for key in (
+                    "solver_steps",
+                    "solver_step_budget",
+                    "fast_path_solves",
+                    "prog_cache_hits",
+                    "prog_cache_misses",
+                    "refined_programs",
+                    "relax_start_wins",
+                )
+            },
+        }
+        # how often the relaxation's own rounding decided a route: the rest
+        # of the rounded programs took a start of the portfolio
+        solver["relax_start_win_rate"] = (
+            solver["relax_start_wins"] / solver["refined_programs"]
+            if solver["refined_programs"]
+            else None
+        )
         telemetry.finalize(
             names=[s.name for s in sims],
             results=results,
             wall_seconds=wall,
-            solver={
-                "mode": engine.solver,
-                **{
-                    key: stats1[key] - solver0[key]
-                    for key in (
-                        "solver_steps",
-                        "solver_step_budget",
-                        "fast_path_solves",
-                        "prog_cache_hits",
-                        "prog_cache_misses",
-                    )
-                },
-                "phases": solver_phases,
-            },
+            solver=solver,
             latency=latency,
             runtime=self.mode,
             n_requests=n_requests,
+            host=host,
         )
         return FleetResult(results=results, telemetry=telemetry, wall_seconds=wall)
 
@@ -494,6 +568,7 @@ class FleetRuntime:
         engine: JRBAEngine,
         telemetry: FleetTelemetry,
         tracer: Tracer,
+        pick: _Stopwatch,
         hits0: int,
         misses0: int,
     ) -> tuple[list[float], list[float], list[float], float]:
@@ -506,85 +581,94 @@ class FleetRuntime:
         total_dispatch = 0.0
         round_idx = 0
         while True:
-            live = [ln for ln in lanes if ln.result is None]
+            with pick:
+                live = [ln for ln in lanes if ln.result is None]
+                # a lane's round may carry several solves (speculative OTFS
+                # batches all waiting jobs); flatten every live lane's round
+                # into one engine call and split the aligned results back
+                solves = [s for ln in live for s in ln.pending.solves]
+                stats = engine.stats
+                calls0, inst0, solve0 = (
+                    stats.batched_solves,
+                    stats.batched_instances,
+                    stats.solve_seconds,
+                )
+                n_requests = sum(1 for ln in live if _round_has_real_solves(ln.pending))
             if not live:
                 break
-            # a lane's round may carry several solves (speculative OTFS
-            # batches all waiting jobs); flatten every live lane's round into
-            # one engine call and split the aligned results back per lane
-            solves = [s for ln in live for s in ln.pending.solves]
-            stats = engine.stats
-            calls0, inst0, solve0 = (
-                stats.batched_solves,
-                stats.batched_instances,
-                stats.solve_seconds,
-            )
-            n_requests = sum(1 for ln in live if _round_has_real_solves(ln.pending))
             t_disp0 = tracer.now() if tracer.enabled else 0.0
             t0 = time.perf_counter()
-            outs = engine.solve_many(
-                [s.net for s in solves],
-                [s.flows for s in solves],
-                capacities=[s.capacity for s in solves],
-                water_filling=[s.water_filling for s in solves],
-            )
+            with _dispatch_span(tracer, round_idx, "barrier", len(solves)):
+                outs = engine.solve_many(
+                    [s.net for s in solves],
+                    [s.flows for s in solves],
+                    capacities=[s.capacity for s in solves],
+                    water_filling=[s.water_filling for s in solves],
+                )
             dispatch_seconds = time.perf_counter() - t0
             total_dispatch += dispatch_seconds
-            per_solve = dispatch_seconds / len(solves) if solves else 0.0
-            stall_round = 0.0
-            off = 0
-            for lane in live:
-                n = len(lane.pending.solves)
-                # barrier attribution: this lane's own share of the batched
-                # dispatch is its solve fraction; everything else it spent
-                # waiting on the other lanes' solves behind the barrier
-                own = per_solve * n
-                stall = dispatch_seconds - own
-                lane_own[lane.idx] += own
-                lane_stall[lane.idx] += stall
-                lane_wall[lane.idx] += dispatch_seconds
-                stall_round += stall
-                if tracer.enabled and dispatch_seconds > 0.0:
-                    trk = lane.sim.scheduler.trace_track
-                    tracer.complete(
-                        "lane/own_solve",
-                        track=trk,
-                        cat="barrier",
-                        ts=t_disp0,
-                        dur=own,
+            with pick:
+                per_solve = dispatch_seconds / len(solves) if solves else 0.0
+                stall_round = 0.0
+                off = 0
+                replies = []
+                for lane in live:
+                    n = len(lane.pending.solves)
+                    # barrier attribution: this lane's own share of the batched
+                    # dispatch is its solve fraction; everything else it spent
+                    # waiting on the other lanes' solves behind the barrier
+                    own = per_solve * n
+                    stall = dispatch_seconds - own
+                    lane_own[lane.idx] += own
+                    lane_stall[lane.idx] += stall
+                    lane_wall[lane.idx] += dispatch_seconds
+                    stall_round += stall
+                    if tracer.enabled and dispatch_seconds > 0.0:
+                        trk = lane.sim.scheduler.trace_track
+                        tracer.complete(
+                            "lane/own_solve",
+                            track=trk,
+                            cat="barrier",
+                            ts=t_disp0,
+                            dur=own,
+                            round=round_idx,
+                            dispatch=round_idx,
+                            n_solves=n,
+                        )
+                        tracer.complete(
+                            "lane/barrier_stall",
+                            track=trk,
+                            cat="barrier",
+                            ts=t_disp0 + own,
+                            dur=stall,
+                            round=round_idx,
+                            dispatch=round_idx,
+                        )
+                    replies.append((outs[off : off + n], own, round_idx))
+                    off += n
+            for lane, reply in zip(live, replies):
+                self._advance(lane, reply)
+            with pick:
+                batch_calls = stats.batched_solves - calls0
+                telemetry.record_round(
+                    RoundRecord(
                         round=round_idx,
-                        n_solves=n,
+                        n_live=len(live),
+                        n_requests=n_requests,
+                        n_solves=len(solves),
+                        batch_calls=batch_calls,
+                        batch_occupancy=(
+                            (stats.batched_instances - inst0) / batch_calls
+                            if batch_calls
+                            else 0.0
+                        ),
+                        solve_seconds=stats.solve_seconds - solve0,
+                        dispatch_seconds=dispatch_seconds,
+                        stall_seconds=stall_round,
+                        cache_hits=stats.cache_hits - hits0,
+                        cache_misses=stats.cache_misses - misses0,
                     )
-                    tracer.complete(
-                        "lane/barrier_stall",
-                        track=trk,
-                        cat="barrier",
-                        ts=t_disp0 + own,
-                        dur=stall,
-                        round=round_idx,
-                    )
-                self._advance(lane, (outs[off : off + n], own))
-                off += n
-            batch_calls = stats.batched_solves - calls0
-            telemetry.record_round(
-                RoundRecord(
-                    round=round_idx,
-                    n_live=len(live),
-                    n_requests=n_requests,
-                    n_solves=len(solves),
-                    batch_calls=batch_calls,
-                    batch_occupancy=(
-                        (stats.batched_instances - inst0) / batch_calls
-                        if batch_calls
-                        else 0.0
-                    ),
-                    solve_seconds=stats.solve_seconds - solve0,
-                    dispatch_seconds=dispatch_seconds,
-                    stall_seconds=stall_round,
-                    cache_hits=stats.cache_hits - hits0,
-                    cache_misses=stats.cache_misses - misses0,
                 )
-            )
             round_idx += 1
         return lane_own, lane_stall, lane_wall, total_dispatch
 
@@ -595,6 +679,7 @@ class FleetRuntime:
         engine: JRBAEngine,
         telemetry: FleetTelemetry,
         tracer: Tracer,
+        pick: _Stopwatch,
         hits0: int,
         misses0: int,
     ) -> tuple[list[float], list[float], list[float], float, dict, int]:
@@ -621,29 +706,30 @@ class FleetRuntime:
             answered None on the spot. An all-empty round is ready
             immediately."""
             nonlocal seq, n_requests
-            req = lane.pending
-            now = time.perf_counter()
-            inflight = _InFlight(
-                lane=lane,
-                solves=req.solves,
-                results=[None] * len(req.solves),
-                remaining=len(req.solves),
-                enqueue_ts=now,
-            )
-            real = False
-            for pos, s in enumerate(req.solves):
-                key = s.bucket if s.bucket is not None else ("unbucketed",)
-                if key == ("empty",):
-                    inflight.remaining -= 1  # result stays None, zero cost
-                    continue
-                real = True
-                queues.setdefault(key, collections.deque()).append(
-                    _QueueEntry(inflight, pos, seq, now)
+            with pick:
+                req = lane.pending
+                now = time.perf_counter()
+                inflight = _InFlight(
+                    lane=lane,
+                    solves=req.solves,
+                    results=[None] * len(req.solves),
+                    remaining=len(req.solves),
+                    enqueue_ts=now,
                 )
-                seq += 1
-            n_requests += real
-            if inflight.remaining == 0:
-                ready.append(inflight)
+                real = False
+                for pos, s in enumerate(req.solves):
+                    key = s.bucket if s.bucket is not None else ("unbucketed",)
+                    if key == ("empty",):
+                        inflight.remaining -= 1  # result stays None, zero cost
+                        continue
+                    real = True
+                    queues.setdefault(key, collections.deque()).append(
+                        _QueueEntry(inflight, pos, seq, now)
+                    )
+                    seq += 1
+                n_requests += real
+                if inflight.remaining == 0:
+                    ready.append(inflight)
 
         def drain_ready() -> None:
             """Resume every completed round's lane; a resumed lane either
@@ -660,7 +746,7 @@ class FleetRuntime:
                 # round rode ran inside its enqueue->answer window), so
                 # own + stall == wall holds exactly, as under lockstep
                 lane_stall[lane.idx] += wall - inflight.own_seconds
-                self._advance(lane, (inflight.results, inflight.own_seconds))
+                self._advance(lane, (inflight.results, inflight.own_seconds, inflight.dispatch))
                 if lane.result is None:
                     enqueue(lane)
 
@@ -669,110 +755,115 @@ class FleetRuntime:
                 enqueue(lane)
         drain_ready()
         while queues:
-            now = time.perf_counter()
-            # scheduling rules, in priority order: (1) a bucket whose head
-            # has waited past the deadline fires first — oldest head wins, so
-            # the latency bound is honored across buckets; (2) a full bucket
-            # fires for throughput — fullest first, oldest head breaking
-            # ties; (3) otherwise nothing is urgent or full, so flush the
-            # oldest head rather than idle (no timers exist to wait on — the
-            # driver is the only source of progress). Rule 3 is also the
-            # no-starvation guarantee: a lone odd-shaped lane's bucket never
-            # fills, but its head becomes the oldest once its elders drain.
-            overdue = [
-                k for k, q in queues.items() if now - q[0].ts >= self.deadline_s
-            ]
-            if overdue:
-                key = min(overdue, key=lambda k: queues[k][0].seq)
-                cause = "deadline"
-            else:
-                full = [k for k, q in queues.items() if len(q) >= self.batch_target]
-                if full:
-                    key = max(full, key=lambda k: (len(queues[k]), -queues[k][0].seq))
-                    cause = "fill"
+            with pick:
+                now = time.perf_counter()
+                # scheduling rules, in priority order: (1) a bucket whose
+                # head has waited past the deadline fires first — oldest
+                # head wins, so the latency bound is honored across buckets;
+                # (2) a full bucket fires for throughput — fullest first,
+                # oldest head breaking ties; (3) otherwise nothing is urgent
+                # or full, so flush the oldest head rather than idle (no
+                # timers exist to wait on — the driver is the only source of
+                # progress). Rule 3 is also the no-starvation guarantee: a
+                # lone odd-shaped lane's bucket never fills, but its head
+                # becomes the oldest once its elders drain.
+                overdue = [
+                    k for k, q in queues.items() if now - q[0].ts >= self.deadline_s
+                ]
+                if overdue:
+                    key = min(overdue, key=lambda k: queues[k][0].seq)
+                    cause = "deadline"
                 else:
-                    key = min(queues, key=lambda k: queues[k][0].seq)
-                    cause = "flush"
-            depth = sum(len(q) for q in queues.values())
-            q = queues[key]
-            take = [q.popleft() for _ in range(min(self.batch_target, len(q)))]
-            if not q:
-                del queues[key]
-            fired_by[cause] += 1
-            solves = [e.inflight.solves[e.pos] for e in take]
-            stats = engine.stats
-            calls0, inst0, solve0 = (
-                stats.batched_solves,
-                stats.batched_instances,
-                stats.solve_seconds,
-            )
+                    full = [k for k, q in queues.items() if len(q) >= self.batch_target]
+                    if full:
+                        key = max(full, key=lambda k: (len(queues[k]), -queues[k][0].seq))
+                        cause = "fill"
+                    else:
+                        key = min(queues, key=lambda k: queues[k][0].seq)
+                        cause = "flush"
+                depth = sum(len(q) for q in queues.values())
+                q = queues[key]
+                take = [q.popleft() for _ in range(min(self.batch_target, len(q)))]
+                if not q:
+                    del queues[key]
+                fired_by[cause] += 1
+                solves = [e.inflight.solves[e.pos] for e in take]
+                stats = engine.stats
+                calls0, inst0, solve0 = (
+                    stats.batched_solves,
+                    stats.batched_instances,
+                    stats.solve_seconds,
+                )
             t0 = time.perf_counter()
-            outs = engine.solve_many(
-                [s.net for s in solves],
-                [s.flows for s in solves],
-                capacities=[s.capacity for s in solves],
-                water_filling=[s.water_filling for s in solves],
-            )
+            with _dispatch_span(tracer, dispatch_idx, cause, len(take)):
+                outs = engine.solve_many(
+                    [s.net for s in solves],
+                    [s.flows for s in solves],
+                    capacities=[s.capacity for s in solves],
+                    water_filling=[s.water_filling for s in solves],
+                )
             dispatch_seconds = time.perf_counter() - t0
             total_dispatch += dispatch_seconds
-            per_solve = dispatch_seconds / len(take)
-            waits = []
-            done: list[_InFlight] = []
-            for e, out in zip(take, outs):
-                inflight = e.inflight
-                inflight.results[e.pos] = out
-                inflight.own_seconds += per_solve
-                inflight.remaining -= 1
-                w = t0 - e.ts
-                waits.append(w)
-                wait_hist.observe(w)
-                if tracer.enabled:
-                    # drawn on the shared engine track, ending where the
-                    # dispatch began: the wait this entry spent queued
-                    tracer.complete(
-                        "queue/wait",
-                        track=engine.trace_track,
-                        cat="queue",
-                        ts=tracer.now() - dispatch_seconds - w,
-                        dur=w,
-                        bucket=str(key),
-                        lane=inflight.lane.idx,
+            with pick:
+                per_solve = dispatch_seconds / len(take)
+                waits = []
+                done: list[_InFlight] = []
+                for e, out in zip(take, outs):
+                    inflight = e.inflight
+                    inflight.results[e.pos] = out
+                    inflight.own_seconds += per_solve
+                    inflight.remaining -= 1
+                    inflight.dispatch = dispatch_idx
+                    w = t0 - e.ts
+                    waits.append(w)
+                    wait_hist.observe(w)
+                    if tracer.enabled:
+                        # drawn on the shared engine track, ending where the
+                        # dispatch began: the wait this entry spent queued
+                        tracer.complete(
+                            "queue/wait",
+                            track=engine.trace_track,
+                            cat="queue",
+                            ts=tracer.now() - dispatch_seconds - w,
+                            dur=w,
+                            bucket=str(key),
+                            lane=inflight.lane.idx,
+                            dispatch=dispatch_idx,
+                        )
+                    if inflight.remaining == 0:
+                        done.append(inflight)
+                batch_calls = stats.batched_solves - calls0
+                telemetry.record_dispatch(
+                    DispatchRecord(
                         dispatch=dispatch_idx,
+                        bucket=str(key),
+                        fired_by=cause,
+                        n_solves=len(take),
+                        # reprolint: allow[DT302] -- cardinality count of live
+                        # steppers; the set is only len()'d, never iterated or
+                        # keyed into, so id() reuse/order can't leak into records
+                        n_lanes=len({id(e.inflight) for e in take}),
+                        queue_depth=depth,
+                        batch_calls=batch_calls,
+                        batch_occupancy=(
+                            (stats.batched_instances - inst0) / batch_calls
+                            if batch_calls
+                            else 0.0
+                        ),
+                        solve_seconds=stats.solve_seconds - solve0,
+                        dispatch_seconds=dispatch_seconds,
+                        queue_wait_mean=sum(waits) / len(waits),
+                        queue_wait_max=max(waits),
+                        cache_hits=stats.cache_hits - hits0,
+                        cache_misses=stats.cache_misses - misses0,
                     )
-                if inflight.remaining == 0:
-                    done.append(inflight)
-            batch_calls = stats.batched_solves - calls0
-            telemetry.record_dispatch(
-                DispatchRecord(
-                    dispatch=dispatch_idx,
-                    bucket=str(key),
-                    fired_by=cause,
-                    n_solves=len(take),
-                    # reprolint: allow[DT302] -- cardinality count of live
-                    # steppers; the set is only len()'d, never iterated or
-                    # keyed into, so id() reuse/order can't leak into records
-                    n_lanes=len({id(e.inflight) for e in take}),
-                    queue_depth=depth,
-                    batch_calls=batch_calls,
-                    batch_occupancy=(
-                        (stats.batched_instances - inst0) / batch_calls
-                        if batch_calls
-                        else 0.0
-                    ),
-                    solve_seconds=stats.solve_seconds - solve0,
-                    dispatch_seconds=dispatch_seconds,
-                    queue_wait_mean=sum(waits) / len(waits),
-                    queue_wait_max=max(waits),
-                    cache_hits=stats.cache_hits - hits0,
-                    cache_misses=stats.cache_misses - misses0,
                 )
-            )
-            dispatch_idx += 1
-            # resume completed rounds in lane order (deterministic regardless
-            # of queue interleaving), each enqueueing its next round before
-            # the dispatcher picks again
-            for inflight in sorted(done, key=lambda i: i.lane.idx):
-                ready.append(inflight)
+                dispatch_idx += 1
+                # resume completed rounds in lane order (deterministic
+                # regardless of queue interleaving), each enqueueing its next
+                # round before the dispatcher picks again
+                for inflight in sorted(done, key=lambda i: i.lane.idx):
+                    ready.append(inflight)
             drain_ready()
         queue_block = {
             "dispatches": dispatch_idx,
@@ -787,11 +878,17 @@ class FleetRuntime:
 
     @staticmethod
     def _advance(lane: _Lane, reply: tuple | None) -> None:
-        """Resume a stepper until its next solve request or completion."""
-        try:
-            lane.pending = lane.gen.send(reply)
-        except StopIteration as stop:
-            lane.pending, lane.result = None, stop.value
+        """Resume a stepper until its next solve request or completion, as
+        one ``lane/step`` span on the lane's track (the stepper's own spans
+        that stay open across its yields are reopened inside it)."""
+        sched = lane.sim.scheduler
+        t0 = time.perf_counter()
+        with sched.tracer.resume("lane/step", track=sched.trace_track, cat="lane"):
+            try:
+                lane.pending = lane.gen.send(reply)
+            except StopIteration as stop:
+                lane.pending, lane.result = None, stop.value
+        lane.step_seconds += time.perf_counter() - t0
 
 
 class AsyncFleetRuntime(FleetRuntime):

@@ -127,6 +127,7 @@ class FleetTelemetry:
         latency: dict | None = None,
         runtime: str = "lockstep",
         n_requests: int | None = None,
+        host: dict | None = None,
     ) -> dict:
         """Aggregate per-scenario throughput and fleet-level rates. ``names``
         groups simulations (several fleet lanes may share one scenario name).
@@ -136,7 +137,9 @@ class FleetTelemetry:
         ``runtime`` tags which driver produced the records; ``n_requests``
         is the lane-round count for drivers without round records (the async
         runtime counts rounds at enqueue time), None to derive it from
-        ``self.rounds``."""
+        ``self.rounds``. ``host`` is the runtime's split of the pass's host
+        time (lane steppers, Algorithm 1 inside them, ``solve_many``, and the
+        driver's own blocks), surfaced verbatim."""
         total_events = sum(r.n_events for r in results)
         recs = [*self.rounds, *self.dispatches]
         by_name: dict[str, list] = {}
@@ -229,8 +232,15 @@ class FleetTelemetry:
             # solver-formulation telemetry for THIS run (mode, relaxation
             # steps actually run vs the fixed dense budget, analytic
             # single-flow fast paths, program-tensor cache traffic) — see
-            # EngineStats; None when the runtime didn't supply it
+            # EngineStats, plus how often the relaxation's argmax start won
+            # the rounding; None when the runtime didn't supply it
             "solver": solver,
+            # who held the host thread during the pass, each measured:
+            # lane_step_seconds (event loop, with alloc_seconds of Algorithm 1
+            # inside it), solve_many_seconds (the engine) and driver_seconds
+            # (queue picks and bookkeeping); the three sum to wall_seconds up
+            # to the driver's loop overhead
+            "host": host,
             # observability block (see FleetRuntime.run): per-lane barrier
             # stall vs own-solve attribution, per-scenario event-latency
             # percentiles (None unless the run observed), and the engine's

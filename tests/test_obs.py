@@ -283,9 +283,12 @@ def test_event_spans_agree_with_sim_records():
     assert sum(kinds.values()) == res.n_events
 
 
-def test_observed_run_is_bit_identical_to_unobserved():
+def test_observed_run_is_bit_identical_to_unobserved(tmp_path):
     """Instrumentation must never perturb scheduling decisions: the same
-    scenario run with tracing+metrics on and off yields identical records."""
+    scenario run with tracing+metrics on and off, and under a profiler
+    capture (every span then also opens an annotation), yields identical
+    records."""
+    import jax
 
     def run(observed):
         net, arrivals = SCENARIOS["edge-mesh-flash"].build(seed=3, n_jobs=6)
@@ -298,10 +301,16 @@ def test_observed_run_is_bit_identical_to_unobserved():
         return sched.run(arrivals)
 
     a, b = run(False), run(True)
-    assert [r.finish_time for r in a.records] == [r.finish_time for r in b.records]
-    assert [r.scheduled for r in a.records] == [r.scheduled for r in b.records]
-    assert a.n_events == b.n_events
-    assert a.n_dispatches == b.n_dispatches
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        c = run(True)
+    finally:
+        jax.profiler.stop_trace()
+    for other in (b, c):
+        assert [r.finish_time for r in a.records] == [r.finish_time for r in other.records]
+        assert [r.scheduled for r in a.records] == [r.scheduled for r in other.records]
+        assert a.n_events == other.n_events
+        assert a.n_dispatches == other.n_dispatches
 
 
 # -- trace_report tool --------------------------------------------------------
