@@ -744,22 +744,133 @@ def link_load_fits(
     return bool(np.all(link_load <= residual + slack))
 
 
-def _greedy_ks(prog: FlowProgram) -> np.ndarray:
-    """Deterministic sequential rounding start: flows in volume-descending
-    order (stable sort — deterministic on ties) each take the path that
-    minimizes the resulting link congestion given the flows already placed.
-    A pure function of the program — no solver output involved — so every
-    solver formulation derives the identical start from the same program."""
-    Nf, K, L = prog.usage.shape
-    ks = np.zeros(Nf, dtype=np.int64)
-    load = np.zeros(L)
-    for i in np.argsort(-prog.volumes, kind="stable"):
-        cand = load[None, :] + prog.usage[i] * prog.volumes[i]  # (K, L)
-        cong = np.max(cand / prog.capacity[None, :], axis=1)
-        cong = np.where(prog.valid[i], cong, np.inf)
-        ks[i] = int(np.argmin(cong))
-        load = load + prog.usage[i, ks[i]] * prog.volumes[i]
+_SWEEPS = 5  # best-response passes a chain may run before it is cut
+
+
+@dataclasses.dataclass
+class _Stack:
+    """Same-shape programs stacked for one batched rounding: ``vu`` is each
+    program's ``usage * volumes`` (the product every sweep step reads),
+    ``order`` each program's sweep order and ``reach`` one past the last
+    position of that order holding a real flow (padding dummies have zero
+    volume and one valid path, so the positions after it are no-ops)."""
+
+    progs: list[FlowProgram]
+    vu: np.ndarray  # (P, Nf, K, L)
+    valid: np.ndarray  # (P, Nf, K)
+    capacity: np.ndarray  # (P, L)
+    order: np.ndarray  # (P, Nf)
+    reach: np.ndarray  # (P,)
+
+    @classmethod
+    def of(cls, progs: list[FlowProgram]) -> "_Stack":
+        vols = np.stack([p.volumes for p in progs])
+        order = np.stack([np.argsort(-p.volumes) for p in progs])
+        return cls(
+            progs=progs,
+            vu=np.stack([p.usage for p in progs]) * vols[:, :, None, None],
+            valid=np.stack([p.valid for p in progs]),
+            capacity=np.stack([p.capacity for p in progs]),
+            order=order,
+            reach=_reach(order, progs),
+        )
+
+
+def _reach(order: np.ndarray, progs: list[FlowProgram]) -> np.ndarray:
+    """One past the last position of each row of ``order`` that holds a real
+    flow (a real flow whose float32 volume underflowed to 0 may sort among
+    the dummies, so this is not always ``n_real``)."""
+    real = order < np.array([p.n_real for p in progs])[:, None]
+    return np.where(real.any(axis=1), order.shape[1] - np.argmax(real[:, ::-1], axis=1), 0)
+
+
+def _greedy_starts(st: _Stack) -> np.ndarray:
+    """Deterministic sequential rounding start of every program in ``st``:
+    flows in volume-descending order (stable sort — deterministic on ties)
+    each take the path that minimizes the resulting link congestion given
+    the flows already placed. A pure function of the program — no solver
+    output involved — so every solver formulation derives the identical
+    start from the same program. Step ``j`` places the ``j``-th flow of
+    every program at once, with the per-program arithmetic unchanged."""
+    P, Nf, K, L = st.vu.shape
+    order = np.stack([np.argsort(-p.volumes, kind="stable") for p in st.progs])
+    reach = _reach(order, st.progs)
+    by_reach = np.argsort(-reach, kind="stable")  # each step's programs: a prefix
+    reach = reach[by_reach]
+    ks = np.zeros((P, Nf), dtype=np.int64)
+    load = np.zeros((P, L))
+    for j in range(int(reach[0])):
+        n = int(np.count_nonzero(reach > j))
+        p = by_reach[:n]
+        i = order[p, j]
+        v = st.vu[p, i]  # (n, K, L)
+        cong = np.max((load[:n, None, :] + v) / st.capacity[p][:, None, :], axis=2)
+        k = np.argmin(np.where(st.valid[p, i], cong, np.inf), axis=1)
+        ks[p, i] = k
+        load[:n] = load[:n] + v[np.arange(n), k]
     return ks
+
+
+def _sweep_chains(
+    st: _Stack, owner: np.ndarray, starts: np.ndarray, sweeps: int = _SWEEPS
+) -> tuple[np.ndarray, np.ndarray]:
+    """Vertex-recovery refinement after argmax rounding, for many chains.
+
+    The paper rounds ``k* = argmax_k m_i^k`` from a *simplex* LP solution,
+    which sits on a vertex (near-integral y). Our mirror-descent solver
+    converges to interior points of the optimal face, where argmax can pick a
+    congested path (e.g. it loses Fig. 2(f)). Best-response sweeps — each
+    flow re-picks the path minimizing the resulting congestion with the
+    others fixed — monotonically reduce the span and recover vertex quality.
+
+    Chain ``c`` starts from its own copy of ``starts[c]`` on program
+    ``owner[c]`` of ``st``. Step ``j`` of a sweep re-picks the ``j``-th flow
+    of every live chain (each in its own program's order) with exactly the
+    arithmetic of a chain swept alone; a chain freezes after a sweep that
+    changed nothing, or after ``sweeps`` sweeps. Returns the chains' routes
+    and the sweeps each ran."""
+    C, Nf = starts.shape
+    ks = starts.copy()
+    done = np.zeros(C, dtype=np.int64)
+    # chains in order of their reach, so each step's live chains are a prefix
+    idx = np.argsort(-st.reach[owner], kind="stable")
+    reach = st.reach[owner[idx]]
+    pos = st.order[owner[idx]]  # (C, Nf) flow at each position
+    vu = st.vu[owner[idx, None], pos]  # (C, Nf, K, L) in sweep order
+    valid = st.valid[owner[idx, None], pos]
+    cap = st.capacity[owner[idx]][:, None, :]
+    kso = np.take_along_axis(ks[idx], pos, axis=1)
+    load = np.stack(
+        [
+            st.progs[p].usage[np.arange(Nf), ks[c]].T @ st.progs[p].volumes
+            for c, p in zip(idx, owner[idx])
+        ]
+    )
+    for sweep in range(1, sweeps + 1):
+        changed = np.zeros(len(idx), dtype=bool)
+        rows = np.arange(len(idx))
+        for j in range(int(reach[0])):
+            n = int(np.count_nonzero(reach > j))
+            v = vu[:n, j]  # (n, K, L)
+            old = kso[:n, j]
+            base = load[:n] - v[rows[:n], old]
+            cong = np.max((base[:, None, :] + v) / cap[:n], axis=2)
+            new = np.argmin(np.where(valid[:n, j], cong, np.inf), axis=1)
+            changed[:n] |= new != old
+            kso[:n, j] = new
+            load[:n] = base + v[rows[:n], new]
+        done[idx] = sweep
+        ends = np.empty_like(kso)
+        np.put_along_axis(ends, pos, kso, axis=1)
+        ks[idx] = ends
+        if sweep == sweeps or not changed.any():
+            break
+        # a chain whose sweep changed nothing stops (no further load
+        # updates: ``load - a + a`` can move the last bit)
+        idx, reach, pos, vu, valid, cap, kso, load = (
+            a[changed] for a in (idx, reach, pos, vu, valid, cap, kso, load)
+        )
+    return ks, done
 
 
 def _rounding_span(prog: FlowProgram, ks: np.ndarray) -> float:
@@ -771,9 +882,13 @@ def _rounding_span(prog: FlowProgram, ks: np.ndarray) -> float:
     return float(np.max((sel.T @ prog.volumes) / prog.capacity))
 
 
-def _round_and_refine(prog: FlowProgram, m: np.ndarray, counts=None) -> np.ndarray:
+def _round_group(
+    progs: list[FlowProgram], ms: list[np.ndarray], counts=None, *, sweeps: int = _SWEEPS
+) -> list[np.ndarray]:
     """Solver-robust rounding: best-response sweeps from a deterministic
-    portfolio of starts, with the relaxation's argmax start consulted last.
+    portfolio of starts, with the relaxation's argmax start consulted last,
+    for programs of one ``usage`` shape — every chain of every program in
+    one batched sweep (:func:`_sweep_chains`).
 
     On symmetric programs — a job's parallel flows between one node pair,
     the common shape in scheduler streams — the relaxed optimum splits each
@@ -783,80 +898,62 @@ def _round_and_refine(prog: FlowProgram, m: np.ndarray, counts=None) -> np.ndarr
     all-same-path vertices and the sweeps repair them into *different* local
     optima. The portfolio makes rounding start-independent exactly there:
     sweep from the greedy sequential start and from every uniform all-k
-    start (both pure functions of the program), keep the best, and let the
-    argmax start win only when *strictly* better. Any all-same-path argmax
-    vertex is already in the portfolio, so in the degenerate regime every
-    formulation returns the identical (and never worse) solution — the
-    property the churn benchmark asserts as zero record deviation.
+    start (both pure functions of the program; a start equal to an earlier
+    start is swept once), keep the first best, and let the argmax start —
+    swept only when it equals none of them — win only when *strictly*
+    better. Any all-same-path argmax vertex is already in the portfolio, so
+    in the degenerate regime every formulation returns the identical (and
+    never worse) solution — the property the churn benchmark asserts as
+    zero record deviation.
 
-    ``counts`` (an :class:`EngineStats`) receives the chains swept, their
-    sweeps, and a relaxation-start win when the argmax start's chain is the
-    one returned."""
-    Nf, K = prog.valid.shape
-    first_valid = np.argmax(prog.valid, axis=1)
-    best_ks: np.ndarray | None = None
-    best = np.inf
-    starts = [_greedy_ks(prog)]
-    for k in range(K):
-        starts.append(np.where(prog.valid[:, k], k, first_valid).astype(np.int64))
-    seen: list[np.ndarray] = []
-    for start in starts:
-        if any(np.array_equal(start, s) for s in seen):
-            continue  # duplicate start -> identical sweep; skip the chain
-        seen.append(start)
-        ks = _best_response_sweeps(prog, start, counts=counts)
-        span = _rounding_span(prog, ks)
-        if span < best:
-            best_ks, best = ks, span
-    start_w = np.argmax(np.where(prog.valid, m, -1.0), axis=1)
-    if any(np.array_equal(start_w, s) for s in seen):
-        # the argmax start is one of the portfolio starts (the degenerate
-        # all-same-path case): its sweep was already scored into best_ks
-        return best_ks
-    ks_w = _best_response_sweeps(prog, start_w, counts=counts)
-    if _rounding_span(prog, ks_w) < best:
-        if counts is not None:
-            counts.relax_start_wins += 1
-        return ks_w
-    return best_ks
-
-
-def _best_response_sweeps(
-    prog: FlowProgram, ks: np.ndarray, *, sweeps: int = 5, counts=None
-) -> np.ndarray:
-    """Vertex-recovery refinement after argmax rounding.
-
-    The paper rounds ``k* = argmax_k m_i^k`` from a *simplex* LP solution,
-    which sits on a vertex (near-integral y). Our mirror-descent solver
-    converges to interior points of the optimal face, where argmax can pick a
-    congested path (e.g. it loses Fig. 2(f)). Best-response sweeps — each
-    flow re-picks the path minimizing the resulting congestion with the
-    others fixed — monotonically reduce the span and recover vertex quality.
-    ``counts`` receives one chain and the number of sweeps it ran.
-    """
-    Nf, K, L = prog.usage.shape
-    order = np.argsort(-prog.volumes)
-    load = prog.usage[np.arange(Nf), ks].T @ prog.volumes  # (L,)
-    done = 0
-    for _ in range(sweeps):
-        done += 1
-        changed = False
-        for i in order:
-            load = load - prog.usage[i, ks[i]] * prog.volumes[i]
-            cand = load[None, :] + prog.usage[i] * prog.volumes[i]  # (K, L)
-            cong = np.max(cand / prog.capacity[None, :], axis=1)
-            cong = np.where(prog.valid[i], cong, np.inf)
-            new_k = int(np.argmin(cong))
-            if new_k != ks[i]:
-                ks[i] = new_k
-                changed = True
-            load = load + prog.usage[i, ks[i]] * prog.volumes[i]
-        if not changed:
-            break
+    ``counts`` (an :class:`EngineStats`) receives one batch, the chains
+    swept, their sweeps, and a relaxation-start win for each program whose
+    argmax start's chain is the one returned."""
+    st = _Stack.of(progs)
+    P, Nf, K, _ = st.vu.shape
+    starts = np.empty((P, K + 2, Nf), dtype=np.int64)
+    starts[:, 0] = _greedy_starts(st)
+    first_valid = np.argmax(st.valid, axis=2)
+    starts[:, 1 : K + 1] = np.where(
+        st.valid.transpose(0, 2, 1), np.arange(K)[None, :, None], first_valid[:, None, :]
+    )
+    starts[:, K + 1] = np.argmax(np.where(st.valid, np.stack(ms), -1.0), axis=2)
+    # starts compared with starts: keep each that equals no earlier one
+    same = (starts[:, :, None, :] == starts[:, None, :, :]).all(axis=3)
+    keep = ~np.tril(same, -1).any(axis=2)
+    owner, slot = np.nonzero(keep)
+    ends, done = _sweep_chains(st, owner, starts[owner, slot], sweeps)
     if counts is not None:
-        counts.refine_chains += 1
-        counts.refine_sweeps += done
-    return ks
+        counts.refine_batches += 1
+        counts.refine_chains += len(owner)
+        counts.refine_sweeps += int(done.sum())
+    out = []
+    for p, prog in enumerate(progs):
+        chains = np.flatnonzero(owner == p)
+        portfolio = chains[slot[chains] <= K]
+        spans = [_rounding_span(prog, ends[c]) for c in portfolio]
+        best = portfolio[int(np.argmin(spans))]
+        ks = ends[best]
+        if keep[p, K + 1] and _rounding_span(prog, ends[chains[-1]]) < min(spans):
+            if counts is not None:
+                counts.relax_start_wins += 1
+            ks = ends[chains[-1]]
+        out.append(ks)
+    return out
+
+
+def _round_and_refine(
+    prog: FlowProgram, m: np.ndarray, counts=None, *, sweeps: int = _SWEEPS
+) -> np.ndarray:
+    """:func:`_round_group` of one program."""
+    return _round_group([prog], [m], counts, sweeps=sweeps)[0]
+
+
+def _fast_start(prog: FlowProgram) -> np.ndarray:
+    """Stand-in relaxation of a single-flow program: every valid path of a
+    flow weighted by its volume, so the argmax start is the first valid
+    path (the engine's analytic fast path, ``JRBAEngine._use_fast_path``)."""
+    return np.where(prog.valid, prog.volumes[:, None], -1.0)
 
 
 def _finalize(
@@ -965,20 +1062,23 @@ class EngineStats:
     # an engine call actually spends: host program build (path enumeration +
     # tensor assembly), program-cache hit replay, device relaxation dispatch,
     # and host rounding/refine/Eq. 15. Identity: solve_seconds ==
-    # dispatch_seconds + (the fast-path share of finalize_seconds).
+    # dispatch_seconds + (the fast-path share of finalize_seconds; a
+    # solve_many call rounds its programs together, so there the share is
+    # its finalize time split by program count).
     build_seconds: float = 0.0  # build_program: path enum + program tensors
     cache_seconds: float = 0.0  # program-cache hits: capacity-only replay
     dispatch_seconds: float = 0.0  # jitted relaxation calls (device dispatch)
     finalize_seconds: float = 0.0  # host rounding / refine / water-filling
     # inside the phases above, each measured around its own work (and drawn
     # as the engine/* span of the same name): operand stacking + upload and
-    # the launch-to-device_get wait split dispatch; the start portfolio of
-    # _round_and_refine sits inside finalize; Yen's enumeration on path-cache
+    # the launch-to-device_get wait split dispatch; the start portfolios'
+    # batched sweeps (_round_group) sit inside finalize; Yen's enumeration on path-cache
     # misses sits inside build, or in candidate_links outside every phase
     stage_seconds: float = 0.0
     wait_seconds: float = 0.0
     refine_seconds: float = 0.0
     refined_programs: int = 0  # programs rounded through the start portfolio
+    refine_batches: int = 0  # batched sweeps: one per program shape a call rounds
     refine_chains: int = 0  # best-response chains swept
     refine_sweeps: int = 0  # best-response passes over a program's flows
     relax_start_wins: int = 0  # the relaxation's argmax start strictly won
@@ -1293,21 +1393,14 @@ class JRBAEngine:
         return ps
 
     def _use_fast_path(self, prog: FlowProgram, refine: bool) -> bool:
-        return self.solver != "dense" and refine and prog.n_real == 1
-
-    def _fast_single(self, prog: FlowProgram, water_filling: bool) -> JRBAResult:
         """Analytic single-flow solve: with one flow the best-response sweep
-        in :func:`_finalize` picks the globally min-congestion candidate path
-        from any starting ``k`` (first argmin on ties), which is exactly
-        where the dense argmax-round-then-refine pipeline lands — so skip
-        the relaxation entirely. The span certificate equals the rounded
-        span (the LP could split traffic lower; nothing downstream consumes
-        the certificate)."""
-        m0 = np.where(prog.valid, prog.volumes[:, None], -1.0)
-        res = self._finalize_one(prog, m0, 0.0, water_filling, True, 0)
-        res.relaxed_span = res.span
-        self.stats.fast_path_solves += 1
-        return res
+        picks the globally min-congestion candidate path from any starting
+        ``k`` (first argmin on ties), which is exactly where the dense
+        argmax-round-then-refine pipeline lands — so skip the relaxation and
+        round from :func:`_fast_start`. The span certificate equals the
+        rounded span (the LP could split traffic lower; nothing downstream
+        consumes the certificate)."""
+        return self.solver != "dense" and refine and prog.n_real == 1
 
     def _solver_options(self) -> dict:
         """The engine's solver settings and observers, as the solve
@@ -1361,26 +1454,41 @@ class JRBAEngine:
         self.stats.solver_step_budget += self.n_iters * n_real
         return solved
 
-    def _finalize_one(
-        self,
-        prog: FlowProgram,
-        m: np.ndarray,
-        relaxed: float,
-        water_filling: bool,
-        refine: bool,
-        steps: int,
-    ) -> JRBAResult:
-        """:func:`_finalize` with the start portfolio timed and counted."""
-        ks = None
-        if refine:
+    def _round(self, progs: list[FlowProgram], ms: list[np.ndarray]) -> list[np.ndarray]:
+        """The start portfolio of every program: one batched sweep (and one
+        ``engine/refine`` span) per ``usage`` shape, timed and counted.
+        Rounding reads no active-link compression, so these groups are
+        wider than the relaxation's."""
+        groups: dict[tuple, list[int]] = {}
+        for j, prog in enumerate(progs):
+            groups.setdefault(prog.usage.shape, []).append(j)
+        out: list[np.ndarray] = [None] * len(progs)
+        for idxs in groups.values():
             t0 = time.perf_counter()
             with self.tracer.span("engine/refine", track=self.trace_track, cat="engine"):
-                ks = _round_and_refine(prog, m, self.stats)
+                ks = _round_group([progs[j] for j in idxs], [ms[j] for j in idxs], self.stats)
             self.stats.refine_seconds += time.perf_counter() - t0
-            self.stats.refined_programs += 1
-        res = _finalize(prog, m, relaxed, water_filling=water_filling, refine=refine, ks=ks)
-        res.relax_steps = steps
-        return res
+            self.stats.refined_programs += len(idxs)
+            for j, k in zip(idxs, ks):
+                out[j] = k
+        return out
+
+    def _finalize_many(self, items: list[tuple], refine: bool) -> list[JRBAResult]:
+        """:func:`_finalize` of ``(prog, m, relaxed, water_filling, steps,
+        fast)`` items, rounded together; ``fast`` marks a single-flow
+        program solved without a relaxation."""
+        ks = self._round([it[0] for it in items], [it[1] for it in items]) if refine else None
+        out = []
+        for j, (prog, m, relaxed, wf, steps, fast) in enumerate(items):
+            res = _finalize(
+                prog, m, relaxed, water_filling=wf, refine=refine, ks=None if ks is None else ks[j]
+            )
+            res.relax_steps = steps
+            if fast:
+                res.relaxed_span = res.span
+                self.stats.fast_path_solves += 1
+            out.append(res)
+        return out
 
     def solve(
         self,
@@ -1395,26 +1503,25 @@ class JRBAEngine:
         prog = self.build(net, flows, capacity=capacity)
         if prog is None:
             return None
-        tracer, track = self.tracer, self.trace_track
-        if self._use_fast_path(prog, refine):
+        fast = self._use_fast_path(prog, refine)
+        if fast:
+            m, relaxed, steps = _fast_start(prog), 0.0, 0
+        else:
+            self._note_shape(("single", self._shape_key(prog), self.n_iters))
             t0 = time.perf_counter()
-            with tracer.span("engine/finalize", track=track, cat="engine"):
-                res = self._fast_single(prog, water_filling)
+            m, relaxed = self._relax_one(prog)
             dt = time.perf_counter() - t0
             self.stats.solve_seconds += dt
-            self.stats.finalize_seconds += dt
-            return res
-        self._note_shape(("single", self._shape_key(prog), self.n_iters))
+            self.stats.dispatch_seconds += dt
+            self.stats.single_solves += 1
+            steps = self._steps[0]
         t0 = time.perf_counter()
-        m, relaxed = self._relax_one(prog)
+        with self.tracer.span("engine/finalize", track=self.trace_track, cat="engine"):
+            (res,) = self._finalize_many([(prog, m, relaxed, water_filling, steps, fast)], refine)
         dt = time.perf_counter() - t0
-        self.stats.solve_seconds += dt
-        self.stats.dispatch_seconds += dt
-        self.stats.single_solves += 1
-        t0 = time.perf_counter()
-        with tracer.span("engine/finalize", track=track, cat="engine"):
-            res = self._finalize_one(prog, m, relaxed, water_filling, refine, self._steps[0])
-        self.stats.finalize_seconds += time.perf_counter() - t0
+        self.stats.finalize_seconds += dt
+        if fast:
+            self.stats.solve_seconds += dt
         return res
 
     def solve_many(
@@ -1466,17 +1573,16 @@ class JRBAEngine:
         ]
         results: list[JRBAResult | None] = [None] * n
         by_bucket: dict[tuple, list[int]] = {}
-        tracer, track = self.tracer, self.trace_track
+        # (result slot, finalize item): every program is relaxed first, then
+        # the call's programs are rounded together
+        pending: list[tuple[int, tuple]] = []
+        n_fast = 0
         for i, p in enumerate(progs):
             if p is None:
                 continue
             if self._use_fast_path(p, refine):
-                t0 = time.perf_counter()
-                with tracer.span("engine/finalize", track=track, cat="engine"):
-                    results[i] = self._fast_single(p, wf[i])
-                dt = time.perf_counter() - t0
-                self.stats.solve_seconds += dt
-                self.stats.finalize_seconds += dt
+                pending.append((i, (p, _fast_start(p), 0.0, wf[i], 0, True)))
+                n_fast += 1
             else:
                 by_bucket.setdefault(self._shape_key(p), []).append(i)
         for shape, idxs in by_bucket.items():
@@ -1498,11 +1604,17 @@ class JRBAEngine:
             self.stats.dispatch_seconds += dt
             self.stats.batched_solves += 1
             self.stats.batched_instances += len(group)
+            for i, prog, (m, relaxed), st in zip(idxs, group, solved, steps):
+                pending.append((i, (prog, m, relaxed, wf[i], st, False)))
+        if pending:
             t0 = time.perf_counter()
-            with tracer.span("engine/finalize", track=track, cat="engine"):
-                for i, prog, (m, relaxed), st in zip(idxs, group, solved, steps):
-                    results[i] = self._finalize_one(prog, m, relaxed, wf[i], refine, st)
-            self.stats.finalize_seconds += time.perf_counter() - t0
+            with self.tracer.span("engine/finalize", track=self.trace_track, cat="engine"):
+                done = self._finalize_many([item for _, item in pending], refine)
+            dt = time.perf_counter() - t0
+            self.stats.finalize_seconds += dt
+            self.stats.solve_seconds += dt * n_fast / len(pending)
+            for (i, _), res in zip(pending, done):
+                results[i] = res
         return results
 
 

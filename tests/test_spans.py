@@ -223,7 +223,11 @@ def test_relax_start_wins_count_only_strict_wins(monkeypatch, argmax_wins):
     m = np.zeros(prog.valid.shape)
     m[0, 1] = 1.0  # the argmax start: flow 0 on path 1, the rest on path 0
     start_w = np.argmax(np.where(prog.valid, m, -1.0), axis=1)
-    monkeypatch.setattr(jrba, "_best_response_sweeps", lambda p, ks, counts=None: ks)
+    monkeypatch.setattr(
+        jrba,
+        "_sweep_chains",
+        lambda st, owner, starts, sweeps=5: (starts.copy(), np.ones(len(starts), dtype=np.int64)),
+    )
     bar = 1.0 if argmax_wins else 3.0
     monkeypatch.setattr(
         jrba, "_rounding_span", lambda p, ks: bar if np.array_equal(ks, start_w) else 2.0
