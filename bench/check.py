@@ -7,10 +7,10 @@ Numbers compared, each beside its limit:
 * ``unfinished``: jobs of the window's passes that never finished;
 * ``record_faults``: job records not done, or whose times run backwards;
 * ``time_faults``: jobs admitted at a time when nothing happened in their
-  lane (no arrival, finish or network change), and, on OTFS lanes whose
-  network holds still, jobs whose time per stream unit is not the plain one
-  of their placement and bandwidths or whose finish is not their admission
-  plus their units at that time each;
+  lane (no arrival, finish, network change or migration round), and, on
+  OTFS lanes whose network holds still, jobs whose time per stream unit is
+  not the plain one of their placement and bandwidths or whose finish is
+  not their admission plus their units at that time each;
 * ``place_faults``: sampled Algorithm 1 placements that differ from the
   plain Algorithm 1 on the same network state;
 * ``answer_faults``: sampled answers that answer other flows than asked, or
@@ -22,7 +22,28 @@ Numbers compared, each beside its limit:
   the congestion and totals of the spread it returned, from the plain
   relaxation over the same candidate paths;
 * ``span_gap``: the largest share by which an answer's span exceeds the
-  best span of the rounding from its relaxation-free starts.
+  best span of the rounding from its relaxation-free starts;
+* ``link_overload``: on OTFS lanes whose network holds still, replayed from
+  the job records alone, the largest share by which the bandwidths of the
+  jobs running together at an admission oversubscribe a link's capacity
+  (each job holds its admission's bandwidths on its routes until its finish;
+  a job finishing at the instant of the admission has released them). OTFS
+  solves each job on the residual its running jobs leave, so no link is
+  oversubscribed; an OTFA lane re-solves every job jointly at each event
+  and its records keep only the last answer, so it is not replayed here.
+
+A configuration adds checks of its own guarantees by name (``"checks":
+["<name>", ...]``): each is a file ``bench/checks/<name>.py`` that holds
+
+* ``LIMITS``: each number it compares and its limit, with the readings the
+  limit was set from written beside it;
+* ``compare(ctx)``: those numbers for one run, from ``ctx``'s ``calls`` and
+  ``placements`` (what ``recorder.py`` kept), ``lane_results`` (each lane's
+  ``fleetgen.Lane``, which holds its churn steps, beside its
+  ``SimResult``), ``seed`` and ``engine`` (the configuration's block).
+
+Their numbers join the nine above, and ``correct`` asks all of them to be
+within their limits.
 
 Where both the answer's span and the reference's exceed the configuration's
 ``max_acceptable_span``, the scheduler admits nothing on either and the job
@@ -34,8 +55,10 @@ share and every path ties.
 from __future__ import annotations
 
 import bisect
+import importlib.util
 import json
 import os
+import re
 
 import numpy as np
 
@@ -58,6 +81,27 @@ TIME_RTOL = 1e-9
 def load_limits() -> dict:
     with open(os.path.join(BENCH, "limits.json")) as f:
         return {k: v["limit"] for k, v in json.load(f)["limits"].items()}
+
+
+def named(names) -> list:
+    """The modules ``bench/checks/<name>.py`` of a configuration's
+    ``checks``; a name without a file, or a number that another check
+    already compares, is an error."""
+    taken = set(load_limits())
+    modules = []
+    for name in names:
+        path = os.path.join(BENCH, "checks", name + ".py")
+        if not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]*", name) or not os.path.exists(path):
+            raise KeyError(f"no check bench/checks/{name}.py")
+        spec = importlib.util.spec_from_file_location("bench_check_" + name.replace(".", "_"),
+                                                      path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        if taken & set(mod.LIMITS):
+            raise ValueError(f"check {name} compares {sorted(taken & set(mod.LIMITS))} again")
+        taken |= set(mod.LIMITS)
+        modules.append(mod)
+    return modules
 
 
 def _sample(n, size, rng, always=()):
@@ -83,7 +127,7 @@ def time_faults(lane_results) -> int:
     for lane, result in lane_results:
         events = {r.submit_time for r in result.records}
         events |= {r.finish_time for r in result.records}
-        events = sorted(events | set(lane.churn_times))
+        events = sorted(events | set(lane.churn_times) | set(lane.migrate_times))
         for r in result.records:
             if not _is_event(r.schedule_time, events):
                 faults += 1
@@ -100,13 +144,42 @@ def time_faults(lane_results) -> int:
     return faults
 
 
+def link_overload(lane_results) -> float:
+    worst = 0.0
+    for lane, result in lane_results:
+        if lane.policy != "OTFS" or lane.churn_times:
+            continue
+        topo = ref.Topology(lane.links)
+        cap = np.maximum(lane.capacity, ref.CAPACITY_FLOOR)
+        held = []  # (admission, finish, load on each link)
+        for r in result.records:
+            if r.schedule_time < 0 or r.bandwidths is None:
+                continue
+            load = np.zeros(len(cap))
+            for route, b in zip(r.routes, r.bandwidths):
+                ids = topo.path_links(list(route))
+                if ids is None:
+                    return float("inf")  # a route that is no path carries nothing anywhere
+                load[ids] += b
+            end = r.finish_time if r.finish_time >= r.schedule_time else np.inf
+            held.append((r.schedule_time, end, load))
+        for t, _, _ in held:
+            running = sum(
+                (load for start, end, load in held
+                 if (start <= t or _close(start, t)) and end > t and not _close(end, t)),
+                np.zeros(len(cap)),
+            )
+            worst = max(worst, float(np.max((running - cap) / cap)))
+    return worst
+
+
 def place_faults(placements, rng) -> int:
     faults = 0
     for i in _sample(len(placements), PLACEMENTS, rng):
         p = placements[i]
         net = p.net
         tasks = [(t.workload, t.mem, t.pinned_node) for t in p.job.tasks]
-        want = ref.algorithm1(net.power, p.mem, net.links, p.capacity, net.link_alive,
+        want = ref.algorithm1(net.power, p.mem, net.links, p.capacity, p.link_alive,
                               tasks, p.job.edges)
         if want is None:
             faults += p.feasible
@@ -183,6 +256,7 @@ def compare(calls, placements, lane_results, seed: int, engine: dict) -> tuple[d
         "bw_gap": float(bw_gap),
         "relax_gap": float(relax_gap),
         "span_gap": float(span_gap),
+        "link_overload": link_overload(lane_results),
     }
     return numbers, {"relax_wins": relax_wins, "placements": len(placements)}
 

@@ -21,6 +21,19 @@ The benchmark's own runs never import this file.
   without the start portfolio and its sweeps;
 * ``first_path``: every flow is routed on its first candidate path;
 * ``place_no_comm``: Algorithm 1 places tasks by compute time alone.
+
+``OTFS_FAULTS`` break what only a cell with OTFS lanes can show:
+
+* ``unreduced``: an admission leaves the residual capacity as it was (the
+  scheduler's state left unchanged): every OTFS job is solved on the whole
+  capacity, as if it ran alone, and jobs that run together oversubscribe
+  the links they share.
+
+``CHURN_FAULTS`` break what only a cell whose network fails can show, for a
+configuration's own checks to catch (``bench/checks/``):
+
+* ``deaf``: the scheduler never learns of a failure: its network keeps every
+  link up, so jobs run on over dead links and nodes.
 """
 from __future__ import annotations
 
@@ -34,6 +47,8 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 FAULTS = ("control", "half", "stale", "altered", "relax_zero", "relax_uniform", "no_sweeps",
           "first_path", "place_no_comm")
+OTFS_FAULTS = ("unreduced",)
+CHURN_FAULTS = ("deaf",)
 
 
 def _route_links(net, route):
@@ -141,11 +156,28 @@ def broken(fault):
                 self.log.placements.append(recorder.Placement(self.net, mem, job, alloc))
             return alloc, flows
 
+    class Unreduced(Scheduler):
+        def __init__(self, net, *args, **kwargs):
+            super().__init__(net, *args, **kwargs)
+            # every read of the residual sees the whole capacity, every
+            # write is dropped
+            whole = property(lambda g: g.capacity.copy(), lambda g, value: None)
+            net.__class__ = type("WholeCapacity", (type(net),), {"residual": whole})
+
+    class Deaf(Scheduler):
+        def __init__(self, net, *args, **kwargs):
+            super().__init__(net, *args, **kwargs)
+            net.fail_link = lambda u, v: False  # fail_node goes through it too
+
     engines = {"control": Control, "half": Half, "stale": Stale, "altered": Altered,
                "relax_zero": RelaxZero, "relax_uniform": RelaxUniform, "no_sweeps": NoSweeps,
                "first_path": FirstPath}
     if fault == "place_no_comm":
         return Engine, PlaceNoComm
+    if fault == "deaf":
+        return Engine, Deaf
+    if fault == "unreduced":
+        return Engine, Unreduced
     return engines[fault], Scheduler
 
 
@@ -167,7 +199,7 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
-    ap.add_argument("--faults", nargs="+", default=["control"], choices=FAULTS)
+    ap.add_argument("--faults", nargs="+", default=["control"], choices=FAULTS + OTFS_FAULTS + CHURN_FAULTS)
     ap.add_argument("--seconds", type=float, default=1.0)
     args = ap.parse_args()
     for fault in args.faults:

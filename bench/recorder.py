@@ -1,10 +1,14 @@
 """What the benchmark keeps of the timed path: every answer the engine gives
 (for the reference check and the kernel's operation count), every Algorithm 1
-placement of a lane whose network holds still, and every admission latency
-(for the tails). All hang off the program's hooks: a ``JRBAEngine`` subclass
-around ``solve_many``, ``build`` and the relaxation, an ``OnlineScheduler``
-subclass around its allocator, and the ``metrics`` object a scheduler reports
-to."""
+placement of a lane whose network holds still, every admission latency
+(for the tails) with its lane's policy, on lanes with a migration SLO the
+simulated time of each migration round (an event of the lane, at which jobs
+may be admitted), and on lanes whose network churns the scheduler's view of
+which links are up at the end of each event. All hang off the program's
+hooks: a ``JRBAEngine`` subclass around ``solve_many``, ``build`` and the
+relaxation, an ``OnlineScheduler`` subclass around its allocator, the
+``metrics`` object a scheduler reports to, and the (disabled) tracer it
+opens its spans on."""
 from __future__ import annotations
 
 from contextlib import nullcontext
@@ -13,16 +17,20 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core import JRBAEngine, OnlineScheduler
+from repro.obs import Tracer
 
 
 class LatencyRecorder:
-    """The ``metrics`` hook of every lane's scheduler: keeps each admission's
-    arrival-to-decision latency verbatim while ``active``."""
+    """The ``metrics`` hook of a lane's scheduler: keeps each admission's
+    arrival-to-decision latency verbatim while ``active``, in ``samples``.
+    ``lane(policy)`` is the hook of one lane that also keeps its samples
+    apart under its policy, in ``by_policy``."""
 
     enabled = True
 
     def __init__(self) -> None:
         self.samples: list[float] = []
+        self.by_policy: dict[str, list[float]] = {}
         self.active = False
 
     def inc(self, name: str, value: float = 1.0) -> None:
@@ -30,6 +38,27 @@ class LatencyRecorder:
 
     def observe(self, name: str, value: float) -> None:
         if self.active and name == "event_latency_s":
+            self.samples.append(value)
+
+    def lane(self, policy: str) -> "LaneLatency":
+        return LaneLatency(self, self.by_policy.setdefault(policy, []))
+
+
+class LaneLatency:
+    """The ``metrics`` hook of one lane's scheduler (``LatencyRecorder.lane``)."""
+
+    enabled = True
+
+    def __init__(self, recorder: LatencyRecorder, samples: list) -> None:
+        self.recorder = recorder
+        self.samples = samples
+
+    def inc(self, name: str, value: float = 1.0) -> None:
+        pass
+
+    def observe(self, name: str, value: float) -> None:
+        if self.recorder.active and name == "event_latency_s":
+            self.recorder.samples.append(value)
             self.samples.append(value)
 
 
@@ -128,26 +157,68 @@ class RecordingEngine(JRBAEngine):
 class Placement:
     """One Algorithm 1 call: the network state it read and what it chose."""
 
-    __slots__ = ("net", "capacity", "mem", "job", "assignment", "feasible")
+    __slots__ = ("net", "capacity", "link_alive", "mem", "job", "assignment", "feasible")
 
     def __init__(self, net, mem, job, alloc):
         self.net = net
         self.capacity = net.capacity.copy()
+        self.link_alive = net.link_alive.copy()
         self.mem = mem
         self.job = job
         self.assignment = np.array(alloc.assignment)
         self.feasible = bool(alloc.feasible)
 
 
+class LaneClock(Tracer):
+    """A disabled tracer, as a scheduler's default, that keeps the simulated
+    time ``t`` of each ``migrate/round`` span the event loop opens and, with
+    ``net``, the network's ``link_alive`` at the end of each ``event/*``
+    span, with that event's time."""
+
+    def __init__(self, net=None) -> None:
+        super().__init__(enabled=False)
+        self.net = net
+        self.times: list[float] = []
+        self.views: list[tuple[float, np.ndarray]] = []
+        self._now = 0.0
+
+    def span(self, name, **kwargs):
+        if name == "migrate/round":
+            self.times.append(kwargs["t"])
+        return super().span(name, **kwargs)
+
+    def begin(self, name, **kwargs):
+        if name.startswith("event/"):
+            self._now = kwargs["t"]
+        return super().begin(name, **kwargs)
+
+    def end(self, name, **kwargs):
+        if self.net is not None and name.startswith("event/"):
+            self.views.append((self._now, self.net.link_alive.copy()))
+        return super().end(name, **kwargs)
+
+
 class RecordingScheduler(OnlineScheduler):
     """``OnlineScheduler`` that logs its Algorithm 1 calls into ``log`` while
-    ``log.active``. Lanes whose network changes under them are not logged:
-    Algorithm 1's bandwidth term there depends on which path it pinned
-    before the change."""
+    ``log.active``. Lanes whose network changes under them (``churned``) are
+    not logged: Algorithm 1's bandwidth term there depends on which path it
+    pinned before the change. With a ``stall_budget``, or on a lane that
+    churns, its tracer is a ``LaneClock``: the time check reads its
+    ``migrate_times``, a configuration's own checks its ``views``."""
 
-    def __init__(self, *args, log=None, **kwargs) -> None:
-        super().__init__(*args, **kwargs)
+    def __init__(self, net, *args, log=None, churned=False, **kwargs) -> None:
+        if (churned or kwargs.get("stall_budget") is not None) and kwargs.get("tracer") is None:
+            kwargs["tracer"] = LaneClock(net if churned else None)
+        super().__init__(net, *args, **kwargs)
         self.log = log
+
+    @property
+    def migrate_times(self) -> list[float]:
+        return self.tracer.times if isinstance(self.tracer, LaneClock) else []
+
+    @property
+    def views(self) -> list:
+        return self.tracer.views if isinstance(self.tracer, LaneClock) else []
 
     def _allocate(self, job, job_id):
         mem = self.net.mem_avail.copy()

@@ -5,7 +5,26 @@
 The cell (``BENCHMARK.json``'s ``workloads``) names a configuration
 (``bench/configs/<config>.json``: the deployment) and a traffic mix
 (``bench/traffic/<traffic>.json``: the driver, the lanes and jobs of a pass,
-their arrivals and churn). The run:
+their arrivals and churn). Beside its topology, job and engine, a
+configuration may state:
+
+* ``"scheduler": {"<policy>": {...}}``: settings of every lane of that
+  policy. Only what a deployment chooses is taken (``fleetgen.SETTINGS``:
+  today ``stall_budget``, the migration SLO of OTFS lanes); the program's
+  reference switches (``speculate``, ``scoped_churn``, ``solver``) and any
+  other key are refused;
+* ``"checks": ["<name>", ...]``: reference checks of its own guarantees,
+  each ``bench/checks/<name>.py`` (``check.py`` says what one holds), whose
+  numbers join the nine every run compares.
+
+A traffic mix may hold a ``"small"`` object (``lanes``, ``jobs_per_lane``,
+``warm``): the size at which the benchmark's CPU tests run it (a mix without
+one is cut to at most 4 lanes of at most 4 jobs there); a run here ignores
+it.
+
+A new deployment is new files: a configuration, a traffic mix, generator
+kinds under ``bench/gen/``, checks under ``bench/checks/``, per-layer readers
+under ``bench/metrics/``, and entries in ``BENCHMARK.json``. The run:
 
 1. refuses to run without a TPU (or with fewer chips than the cell asks
    for) and on a device kind that ``bench/peaks.json`` does not know;
@@ -18,9 +37,13 @@ their arrivals and churn). The run:
    ``FleetRuntime.run`` until ``--seconds`` of pass time have elapsed; the
    pass in progress finishes;
 5. reads the device's peak memory, then compares the window's answers,
-   placements and records with the plain reference (``check.py``);
+   placements and records with the plain reference (``check.py``, and the
+   configuration's own checks);
 6. prints the end-to-end metrics (``--trace 0``) or the per-layer metrics
-   read from a profiler trace of the window (``--trace 1``).
+   read from a profiler trace of the window (``--trace 1``). Beside them,
+   ``samples`` holds what a reader of a far-off run needs: each pass's wall
+   and CPU seconds, the garbage collections and backend compiles inside the
+   window, and each lane policy's median admission latency.
 
 The last line of standard output is one JSON object; the numbers compared
 for ``correct`` are also the last lines of standard error.
@@ -32,6 +55,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -198,6 +222,12 @@ def main(argv=None, *, devices=None) -> dict:
     cell, config, traffic, spec = load_cell(args.workload)
 
     sys.path[:0] = [os.path.join(ROOT, "src"), BENCH]
+    import check
+
+    try:
+        named_checks = check.named(config.get("checks", []))
+    except (KeyError, ValueError) as e:
+        raise Refused(str(e)) from None
     os.environ["JAX_COMPILATION_CACHE_DIR"] = CACHE_DIR
     import jax
 
@@ -206,15 +236,20 @@ def main(argv=None, *, devices=None) -> dict:
 
     from repro.compile_cache import enable_compile_cache
     from repro.fleet import FleetRuntime
+    from repro.obs import compiles
 
-    import check
     import devtrace
-    from fleetgen import build_pass
+    from fleetgen import build_pass, scheduler_settings
     from recorder import LatencyRecorder, PlacementLog, RecordingEngine, RecordingScheduler
     from stats import percentile
 
+    try:
+        settings = scheduler_settings(config)
+    except ValueError as e:
+        raise Refused(str(e)) from None
     jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
     enable_compile_cache()
+    compiles.listen()
     trace = bool(args.trace)
     engine = RecordingEngine(
         k=config["engine"]["k"], n_iters=config["engine"]["n_iters"], annotate=trace
@@ -234,7 +269,8 @@ def main(argv=None, *, devices=None) -> dict:
         return RecordingScheduler(
             net, policy, k_paths=engine.k, jrba_iters=engine.n_iters, engine=engine,
             max_acceptable_span=config["engine"]["max_acceptable_span"],
-            metrics=latency, log=None if churned else placements,
+            metrics=latency.lane(policy), log=None if churned else placements, churned=churned,
+            **settings.get(policy, {}),
         )
 
     # warm-up: every shape the mix can send, compiled (or read from the
@@ -251,8 +287,18 @@ def main(argv=None, *, devices=None) -> dict:
 
     # the window: passes 1, 2, ... until --seconds of pass time
     stats0 = engine.stats.as_dict()
+    compiles0 = compiles.snapshot()
     lane_results, telemetry, walls = [], [], []
+    # per pass, the process's CPU seconds: a pass whose wall time grows
+    # without them waited off the CPU
+    cpu_s = []
+    collections = []  # Python's garbage collections in the window: start, end
+
+    def collected(phase, info):
+        collections.append(time.perf_counter())
+
     latency.active = engine.recording = placements.active = True
+    gc.callbacks.append(collected)
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     if trace:
         options = jax.profiler.ProfileOptions()
@@ -263,11 +309,17 @@ def main(argv=None, *, devices=None) -> dict:
             sims, lanes = build_pass(engine, config, traffic, args.seed, len(walls) + 1,
                                      make_scheduler)
             with jax.profiler.TraceAnnotation("bench/pass") if trace else nullcontext():
-                t0 = time.perf_counter()
+                t0, c0 = time.perf_counter(), time.process_time()
                 res = runtime.run(sims)
                 walls.append(time.perf_counter() - t0)
+                cpu_s.append(time.process_time() - c0)
+            for lane, sim in zip(lanes, sims):
+                lane.migrate_times = sim.scheduler.migrate_times
+                lane.views = sim.scheduler.views
             lane_results.extend(zip(lanes, res.results))
             telemetry.append(res.telemetry.summary)
+    gc.callbacks.remove(collected)
+    compiled = compiles.snapshot() - compiles0
     if trace:
         jax.profiler.stop_trace()
     latency.active = engine.recording = placements.active = False
@@ -283,7 +335,13 @@ def main(argv=None, *, devices=None) -> dict:
     numbers, seen = check.compare(
         engine.calls, placements.placements, lane_results, args.seed, config["engine"]
     )
-    correct, checks = check.judge(numbers, check.load_limits())
+    limits = check.load_limits()
+    check_ctx = {"calls": engine.calls, "placements": placements.placements,
+                 "lane_results": lane_results, "seed": args.seed, "engine": config["engine"]}
+    for own in named_checks:
+        numbers.update(own.compare(check_ctx))
+        limits.update(own.LIMITS)
+    correct, checks = check.judge(numbers, limits)
     print(f"reference check {time.perf_counter() - t_check!r} s, {seen}", file=sys.stderr)
     attempted = sum(len(r.records) for r in results)
 
@@ -304,10 +362,21 @@ def main(argv=None, *, devices=None) -> dict:
             "admit_p95_ms": percentile(ms, 95),
             "setup_s": setup_s,
         }
+        for policy, samples in latency.by_policy.items():
+            if samples:
+                values[f"admit_p50_ms.{policy.lower()}"] = percentile(
+                    [1e3 * s for s in samples], 50)
         for m in spec["end_to_end"]:
             if args.workload in m.get("workloads", [args.workload]):
                 metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
-        out["samples"] = {"passes": len(walls), "events": events, "admissions": len(ms)}
+        gc_s = [b - a for a, b in zip(collections[::2], collections[1::2])]
+        out["samples"] = {"passes": len(walls), "events": events, "admissions": len(ms),
+                          "migrations": sum(r.migrations for r in results),
+                          "pass_s": walls, "gc_s": sum(gc_s), "gc_max_s": max(gc_s, default=0.0),
+                          "pass_cpu_s": cpu_s,
+                          "compiles": list(compiled),
+                          "p50_ms": {p: values[f"admit_p50_ms.{p.lower()}"]
+                                     for p, v in latency.by_policy.items() if v}}
     else:
         reduced = devtrace.load(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)

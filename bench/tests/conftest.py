@@ -33,13 +33,32 @@ SMALL = {
     "lockstep": {"lanes": 4, "warm": WARM},
     "backlog": {"lanes": 2, "jobs_per_lane": 4, "warm": WARM},
 }
+# the cut of a mix that ``SMALL`` does not name and that holds no "small"
+CUT = {"lanes": 4, "jobs_per_lane": 4}
+
+
+def small_traffic(name: str, traffic: dict) -> dict:
+    """Mix ``name`` at a CPU run's size: ``SMALL``'s entry where it names the
+    mix; else the mix's own ``"small"`` object (``lanes``, ``jobs_per_lane``,
+    ``warm``) over a cut to at most ``CUT``'s lanes and jobs with the
+    one-tier ``WARM`` grid. A new mix needs no entry here."""
+    if name in SMALL:
+        return dict(traffic, **SMALL[name])
+    cut = {key: min(traffic[key], most) for key, most in CUT.items()}
+    return {**traffic, **cut, "warm": WARM, **traffic.get("small", {})}
+
+
+def chips(run, name: str) -> list:
+    """One stand-in chip for each chip cell ``name`` asks for."""
+    return [FakeChip() for _ in range(run.load_cell(name)[0]["chips"])]
 
 
 @pytest.fixture
 def small_run(monkeypatch, tmp_path):
     """``run_cell(cell, seed, fault=None, trace=0)``: one run of ``cell``
-    through ``run.main`` at the ``SMALL`` size on the CPU, optionally with a
-    planted fault from ``control.py``. Returns the result object."""
+    through ``run.main`` at its small size (``small_traffic``) on the CPU,
+    handed one stand-in chip for each chip the cell asks for, optionally with
+    a planted fault from ``control.py``. Returns the result object."""
     import control
     import run
 
@@ -49,7 +68,7 @@ def small_run(monkeypatch, tmp_path):
 
     def load_small(name):
         cell, config, traffic, spec = original(name)
-        return cell, config, dict(traffic, **SMALL[cell["traffic"]]), spec
+        return cell, config, small_traffic(cell["traffic"], traffic), spec
 
     monkeypatch.setattr(run, "load_cell", load_small)
 
@@ -57,7 +76,7 @@ def small_run(monkeypatch, tmp_path):
         argv = ["--workload", cell, "--seed", str(seed), "--seconds", "0.01",
                 "--trace", str(trace)]
         if fault is None:
-            return run.main(argv, devices=[FakeChip()])
-        return control.run_broken(fault, argv, devices=[FakeChip()])
+            return run.main(argv, devices=chips(run, cell))
+        return control.run_broken(fault, argv, devices=chips(run, cell))
 
     return run_cell

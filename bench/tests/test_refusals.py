@@ -7,7 +7,7 @@ import sys
 
 import pytest
 
-from conftest import BENCH, ROOT
+from conftest import BENCH, ROOT, FakeChip
 
 
 def _run(cwd, env_extra=None):
@@ -49,3 +49,26 @@ def test_unknown_workload_is_refused():
     with pytest.raises(SystemExit) as err:
         run.load_cell("no-such.cell")
     assert err.value.code != 0
+
+
+@pytest.mark.parametrize("patch", [
+    {"checks": ["no_such_check"]},
+    {"checks": ["../limits"]},
+    {"scheduler": {"OTFA": {"stall_budget": 1.0}}},
+    {"scheduler": {"OTFS": {"scoped_churn": False}}},
+    {"scheduler": {"OTFS": {"solver": "dense"}}},
+])
+def test_a_configuration_is_refused_what_no_deployment_states(patch, monkeypatch):
+    import run
+
+    original = run.load_cell
+
+    def load(name):
+        cell, config, traffic, spec = original(name)
+        return cell, dict(config, **patch), traffic, spec
+
+    monkeypatch.setattr(run, "load_cell", load)
+    with pytest.raises(SystemExit) as err:
+        run.main(["--workload", "ents-mesh.otfa", "--seed", "1", "--seconds", "1"],
+                 devices=[FakeChip()])
+    assert err.value.code == 2
